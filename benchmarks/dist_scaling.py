@@ -20,10 +20,14 @@ scaling efficiency on this box therefore measures shard_map dispatch
 overhead, not parallel speedup, and is recorded as-is with the core count
 beside it. On real multi-chip hardware the same cell measures true scaling.
 
-The benchmark needs the device count forced *before* jax initialises, so
-``run()`` re-execs itself in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` when the current
-process sees fewer than 8 devices.
+On the CPU backend the device count must be forced *before* jax
+initialises, so ``run()`` re-execs itself in a subprocess with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` when the environment
+names the CPU platform (``JAX_PLATFORMS=cpu``) and ``XLA_FLAGS`` does not
+force the count yet. It decides from the environment alone: a parent that
+touched jax would hold a TPU and its child could not get it. Anywhere else
+it runs in-process on the devices it finds, over the mesh sizes they allow
+(the 4-device parity and traffic records need at least 4).
 """
 
 from __future__ import annotations
@@ -78,18 +82,14 @@ def _build_problem():
 
 def _inner(out_path: str = "BENCH_dist.json") -> None:
     import jax
-    import jax.numpy as jnp
-    import numpy as np
 
     from benchmarks.common import append_cell, emit, time_fn
     from repro.data.loader import stack_batches
-    from repro.launch import jaxpr_stats
     from repro.launch.mesh import data_parallel_mesh
     from repro.launch.train import MeshTrainer
     from repro.train import optimizer as opt_lib
 
-    assert len(jax.devices()) >= MAX_DEVICES, \
-        f"needs {MAX_DEVICES} forced host devices, run() handles the re-exec"
+    sizes = [d for d in (1, 2, 4, 8) if d <= len(jax.devices())]
     make_loader, loss_fn, params = _build_problem()
     cfg = opt_lib.OptConfig(lr=1e-2, warmup_steps=1, total_steps=100)
     state0 = opt_lib.init_state(params, cfg)
@@ -97,7 +97,7 @@ def _inner(out_path: str = "BENCH_dist.json") -> None:
     meshes, batches_by_d, trainers = {}, {}, {}
     per_mesh = {}
     base_us = None
-    for d in (1, 2, 4, 8):
+    for d in sizes:
         mesh = data_parallel_mesh(d)
         trainer = MeshTrainer(loss_fn, cfg, mesh=mesh)
         batches = list(make_loader(shards=d))
@@ -120,6 +120,36 @@ def _inner(out_path: str = "BENCH_dist.json") -> None:
         emit(f"dist/step_{d}dev_us", us,
              f"eff={eff:.2f} traces={trainer.trace_count}")
         meshes[d], trainers[d], batches_by_d[d] = mesh, trainer, batches
+
+    rec = {
+        "cell": "dist_scaling",
+        "backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
+        "host_cpu_count": os.cpu_count(),
+        "devices": len(jax.devices()),
+        "global_batch": GLOBAL_BATCH,
+        "fanouts": FANOUTS,
+        "per_mesh": per_mesh,
+        "note": ("forced host devices timeshare host_cpu_count cores; "
+                 "wall-clock efficiency on this box measures dispatch "
+                 "overhead, not parallel speedup"),
+    }
+    if 4 in sizes:
+        rec.update(_parity_and_traffic(trainers, meshes, batches_by_d,
+                                       loss_fn, cfg, state0))
+    append_cell(out_path, rec)
+
+
+def _parity_and_traffic(trainers, meshes, batches_by_d, loss_fn, cfg,
+                        state0) -> dict:
+    """4-device parity vs gradient accumulation + collective bytes."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.common import emit
+    from repro.launch import jaxpr_stats
+    from repro.launch.train import MeshTrainer
+    from repro.train import optimizer as opt_lib
 
     # ---- 4-device grad/loss parity vs single-device accumulation ----
     d = 4
@@ -169,13 +199,7 @@ def _inner(out_path: str = "BENCH_dist.json") -> None:
         comm[method] = int(stats["collective_bytes"])
         emit(f"dist/collective_bytes_{method}", comm[method])
 
-    rec = {
-        "cell": "dist_scaling",
-        "host_cpu_count": os.cpu_count(),
-        "forced_host_devices": MAX_DEVICES,
-        "global_batch": GLOBAL_BATCH,
-        "fanouts": FANOUTS,
-        "per_mesh": per_mesh,
+    return {
         "parity_4dev": {"param_maxdiff": param_diff,
                         "loss_maxdiff": loss_diff, "tolerance": 1e-5,
                         "pass": bool(param_diff <= 1e-5
@@ -183,26 +207,19 @@ def _inner(out_path: str = "BENCH_dist.json") -> None:
         "collective_bytes_per_step": comm,
         "compression_saving_int8":
             1.0 - comm["int8"] / max(comm["raw"], 1),
-        "note": ("forced host devices timeshare host_cpu_count cores; "
-                 "wall-clock efficiency on this box measures dispatch "
-                 "overhead, not parallel speedup"),
     }
-    append_cell(out_path, rec)
 
 
 def run(out_path: str = "BENCH_dist.json") -> None:
-    """Entry point for run.py: re-exec with forced devices if needed."""
-    import jax
-
+    """Entry point for run.py: re-exec with forced host devices on CPU."""
     from repro.launch.mesh import HOST_DEVICE_FLAG, host_device_flag
 
-    if len(jax.devices()) >= MAX_DEVICES:
+    flags = os.environ.get("XLA_FLAGS", "")
+    if os.environ.get("JAX_PLATFORMS") != "cpu" or HOST_DEVICE_FLAG in flags:
         _inner(out_path)
         return
     env = dict(os.environ)
-    flags = env.get("XLA_FLAGS", "")
-    if HOST_DEVICE_FLAG not in flags:
-        env["XLA_FLAGS"] = f"{flags} {host_device_flag(MAX_DEVICES)}".strip()
+    env["XLA_FLAGS"] = f"{flags} {host_device_flag(MAX_DEVICES)}".strip()
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     src = os.path.join(root, "src")
     env["PYTHONPATH"] = os.pathsep.join(

@@ -1,18 +1,25 @@
 """Benchmark aggregator: one function per paper table/claim.
 
 Prints ``name,us_per_call,derived`` CSV rows (see per-module docstrings for
-protocols). Heavy dry-run cells are *not* recompiled here — the roofline
-table reads the cached ``results/dryrun`` JSONs (regenerate via
-``python -m repro.launch.dryrun --all``).
+protocols). Every suite runs in this one process, so on a TPU the process
+that holds the chip is the one that uses it. Any suite that raises makes the
+run exit non-zero. The dry-run roofline table is not part of this run:
+``python -m repro.launch.dryrun --all`` then ``python -m
+benchmarks.roofline``.
 """
 
 from __future__ import annotations
 
+import os
 import sys
 import traceback
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+
+    compile_cache.enable(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     from benchmarks import (chaos_recovery, dist_scaling,
                             explainer_fidelity, fastpath_audit,
                             grouped_matmul_bench, sampler_throughput,
@@ -43,16 +50,6 @@ def main() -> None:
         except Exception:
             failed.append(name)
             traceback.print_exc()
-    print("# ---- roofline (cached dry-run) ----")
-    try:
-        import benchmarks.roofline as roofline
-        for rec in roofline.load("results/dryrun", "1pod"):
-            if rec["status"] == "ok":
-                print(f"roofline/{rec['arch']}/{rec['shape']},"
-                      f"{max(rec['t_compute_s'], rec['t_memory_s'], rec['t_collective_s']) * 1e6:.1f},"
-                      f"dom={rec['dominant']} frac={roofline.fraction(rec):.4f}")
-    except Exception:
-        traceback.print_exc()
     if failed:
         print(f"# FAILED: {failed}", file=sys.stderr)
         sys.exit(1)

@@ -48,18 +48,18 @@ def main(epochs=3, batch_size=128, lr=0.01):
     model = make_model("sage", 32, 64, 4, num_layers=2)
     params = model.init(jax.random.PRNGKey(0))
 
-    import functools
-
-    @functools.partial(jax.jit, static_argnums=(5, 6))
-    def train_step(params, x, edge_index, seed_slots, y,
-                   nodes_per_hop, edges_per_hop):
+    # The loader's Batch is a pytree: passed into jit whole, its EdgeIndex
+    # keeps the host-packed ELL caches, so on a TPU every aggregation runs
+    # the Pallas kernel; its per-hop counts are static.
+    @jax.jit
+    def train_step(params, batch):
         def loss_fn(p):
-            out = model.apply(p, x, edge_index,
-                              num_sampled_nodes_per_hop=nodes_per_hop,
-                              num_sampled_edges_per_hop=edges_per_hop,
+            out = model.apply(p, batch.x, batch.edge_index,
+                              num_sampled_nodes_per_hop=batch.num_sampled_nodes,
+                              num_sampled_edges_per_hop=batch.num_sampled_edges,
                               trim=True)
-            logp = jax.nn.log_softmax(out[seed_slots])
-            return -jnp.take_along_axis(logp, y[:, None], 1).mean()
+            logp = jax.nn.log_softmax(out[batch.seed_slots])
+            return -jnp.take_along_axis(logp, batch.y[:, None], 1).mean()
 
         loss, grads = jax.value_and_grad(loss_fn)(params)
         params = jax.tree_util.tree_map(lambda p, g: p - lr * g, params,
@@ -69,10 +69,7 @@ def main(epochs=3, batch_size=128, lr=0.01):
     for epoch in range(epochs):
         losses = []
         for batch in loader:
-            params, loss = train_step(
-                params, batch.x, batch.edge_index.data, batch.seed_slots,
-                batch.y, tuple(batch.num_sampled_nodes),
-                tuple(batch.num_sampled_edges))
+            params, loss = train_step(params, batch)
             losses.append(float(loss))
         print(f"epoch {epoch}: loss={np.mean(losses):.4f}")
 

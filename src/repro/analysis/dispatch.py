@@ -28,6 +28,8 @@ from typing import Any, Dict, List, Tuple
 
 import jax
 
+from repro.kernels import pallas_kernel_name
+
 ORACLE_SCOPE = "repro_oracle:"
 KERNEL_VJP_SCOPE = "repro_kernel_vjp:"
 
@@ -84,6 +86,9 @@ class DispatchReport:
     unattributed_gather_scatter: Dict[str, int] = dataclasses.field(
         default_factory=dict)
     collective_eqns: Dict[str, int] = dataclasses.field(default_factory=dict)
+    # pallas_call launches traced with ``interpret`` set: the Pallas
+    # interpreter, not a compiled kernel (expected off-TPU, a fault on it)
+    interpret_launches: int = 0
     total_eqns: int = 0
     dynamic_trip_warnings: int = 0
 
@@ -139,6 +144,7 @@ class DispatchReport:
         """JSON-ready summary (the benchmark audit cell's payload)."""
         return {
             "kernel_launches": dict(self.kernel_launches),
+            "interpret_launches": self.interpret_launches,
             "oracle_fallback_eqns": dict(self.oracle_eqns),
             "oracle_fallbacks": self.oracle_fallbacks,
             "kernel_vjp_eqns": dict(self.kernel_vjp_eqns),
@@ -159,11 +165,11 @@ def audit_jaxpr(jaxpr, mult: int = 1,
     for eqn in inner.eqns:
         name = eqn.primitive.name
         if name == "pallas_call":
-            info = eqn.params.get("name_and_src_info")
-            kernel = getattr(info, "name", None) or eqn.params.get(
-                "name", "<unnamed>")
+            kernel = pallas_kernel_name(eqn)
             report.kernel_launches[kernel] = report.kernel_launches.get(
                 kernel, 0) + mult
+            if eqn.params.get("interpret"):
+                report.interpret_launches += mult
             report.total_eqns += mult
             continue
         if name in _COLLECTIVES:

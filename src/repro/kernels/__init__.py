@@ -54,6 +54,33 @@ def forward_only_pallas(impl, num_static: int, message: str):
     return wrapped
 
 
+def pallas_kernel_name(eqn) -> str:
+    """Kernel name of a ``pallas_call`` jaxpr eqn.
+
+    Every kernel in this package passes ``name=`` to ``pallas_call``; the
+    kernel function's own name (from the traced kernel jaxpr) is the
+    fallback for a call that does not. The dispatch auditor and the FLOP
+    cost table both key on this name.
+    """
+    name = eqn.params.get("name")
+    if name:
+        return name
+    debug = getattr(eqn.params.get("jaxpr"), "debug_info", None)
+    return getattr(debug, "func_name", None) or "<unnamed>"
+
+
+def interpret_default() -> bool:
+    """Interpret mode for kernels whose caller did not choose: True off-TPU.
+
+    Off-TPU the kernels can only run in the Pallas interpreter; on a TPU
+    they compile. ``analysis.audit_report`` counts interpret-mode launches,
+    so a chip run that fell back to the interpreter is caught there.
+    """
+    import jax
+
+    return jax.default_backend() != "tpu"
+
+
 def use_pallas() -> bool:
     """Whether to dispatch Pallas kernels (TPU) or the jnp oracle (CPU/XLA).
 

@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import budgets as hw_budgets, use_pallas
+from repro.kernels import budgets as hw_budgets, interpret_default, use_pallas
 from repro.kernels.attention import ref
 from repro.kernels.attention.gat_attention import (DEFAULT_BR,
                                                    attn_ell_pallas,
@@ -119,7 +119,6 @@ def _gat_ell_pallas_chunked(ell_idx: jnp.ndarray, adst: jnp.ndarray,
     rows, k = ell_idx.shape
     heads, feat = z.shape[1], z.shape[2]
     z2d = z.reshape(z.shape[0], heads * feat)
-    bf = 128 if feat % 128 == 0 else feat
     # Launch-time backstop against the *declared* hardware budgets (the
     # pack-time check covers loader layouts; ad-hoc buckets land here).
     hw_budgets.check_gat_bucket(rows, k, heads, feat,
@@ -128,7 +127,7 @@ def _gat_ell_pallas_chunked(ell_idx: jnp.ndarray, adst: jnp.ndarray,
     chunk -= chunk % DEFAULT_BR
     if rows <= chunk:
         out = gat_ell_pallas(ell_idx, adst, ell_w, alpha_src, z2d,
-                             negative_slope=negative_slope, block_feat=bf,
+                             negative_slope=negative_slope,
                              interpret=interpret)
         return out.reshape(rows, heads, feat)
     outs = []
@@ -137,8 +136,7 @@ def _gat_ell_pallas_chunked(ell_idx: jnp.ndarray, adst: jnp.ndarray,
         outs.append(gat_ell_pallas(
             ell_idx[lo:hi], adst[lo:hi],
             None if ell_w is None else ell_w[lo:hi], alpha_src, z2d,
-            negative_slope=negative_slope, block_feat=bf,
-            interpret=interpret))
+            negative_slope=negative_slope, interpret=interpret))
     return jnp.concatenate(outs, axis=0).reshape(rows, heads, feat)
 
 
@@ -265,7 +263,6 @@ def _attn_ell_pallas_chunked(ell_idx, adst, ell_w, prior, alpha_src, z,
     adst2d = adst.reshape(adst.shape[0], heads * ld)
     z2d = z.reshape(z.shape[0], heads * feat)
     prior2d = jnp.asarray(prior, jnp.float32).reshape(1, heads)
-    bf = 128 if feat % 128 == 0 else feat
     # Launch-time backstop against the *declared* hardware budgets, over
     # the full typed shape (prior row + carry buffers included).
     hw_budgets.check_attn_bucket(rows, k, heads, feat, logit_dim=ld,
@@ -276,7 +273,7 @@ def _attn_ell_pallas_chunked(ell_idx, adst, ell_w, prior, alpha_src, z,
         acc, m, l = attn_ell_pallas(ell_idx, adst2d, ell_w, prior2d, a2d,
                                     z2d, logit_kind=logit_kind,
                                     negative_slope=negative_slope,
-                                    block_feat=bf, interpret=interpret)
+                                    interpret=interpret)
         return m, l, acc.reshape(rows, heads, feat)
     ms, ls, accs = [], [], []
     for lo in range(0, rows, chunk):
@@ -285,7 +282,7 @@ def _attn_ell_pallas_chunked(ell_idx, adst, ell_w, prior, alpha_src, z,
             ell_idx[lo:hi], adst2d[lo:hi],
             None if ell_w is None else ell_w[lo:hi], prior2d, a2d, z2d,
             logit_kind=logit_kind, negative_slope=negative_slope,
-            block_feat=bf, interpret=interpret)
+            interpret=interpret)
         ms.append(m)
         ls.append(l)
         accs.append(acc)
@@ -376,8 +373,7 @@ def attn_carry_ell(buckets: Sequence[EllBucket], alpha_src: jnp.ndarray,
         adst = _bucket_gather(row_ids, alpha_dst, ell_idx.shape[0])
         w_b = _bucket_ell_w(ell_pos, edge_weight)
         if take_pallas:
-            itp = (jax.default_backend() != "tpu") if interpret is None \
-                else interpret
+            itp = interpret_default() if interpret is None else interpret
             mb, lb, accb = _attn_ell_carry_diff(
                 kind, float(slope), bool(itp), ell_idx, adst, w_b,
                 prior_eff, alpha_src, z)
@@ -464,8 +460,7 @@ def gat_attend_ell(buckets: Sequence[EllBucket], alpha_src: jnp.ndarray,
                             jnp.asarray(edge_weight)[jnp.maximum(pos, 0)],
                             0.0).astype(jnp.float32)
         if take_pallas:
-            itp = (jax.default_backend() != "tpu") if interpret is None \
-                else interpret
+            itp = interpret_default() if interpret is None else interpret
             res = _gat_ell_pallas_diff(float(negative_slope), bool(itp),
                                        ell_idx, adst, w_b, alpha_src, z)
         else:

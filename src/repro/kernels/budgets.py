@@ -25,16 +25,23 @@ from typing import Dict, Sequence, Tuple
 import numpy as np
 
 # --------------------------------------------------------------- constants
-# The neighbor table rides scalar prefetch into SMEM on real TPUs, which is
-# KB-scale: bound the per-launch table and chunk the row dimension above it.
-# 64k int32 = 256 KB per launch; shapes are host-known so the chunk loop is
-# a static Python loop (one pallas_call per chunk, shared compiled kernel
-# across equal-shaped chunks).
+# The neighbor table rides scalar prefetch into SMEM, flattened to 1-D (a
+# 2-D SMEM table pads its minor dim to 128 words). Bound the per-launch
+# table and chunk the row dimension above it. 64k int32 = 256 KB per
+# launch; shapes are host-known so the chunk loop is a static Python loop
+# (one pallas_call per chunk, shared compiled kernel across equal-shaped
+# chunks).
 MAX_PREFETCH_ELEMS = 64 * 1024
 
-# Declared per-core budgets (TPU v4-class; conservative so CPU interpret
-# runs enforce the same discipline the hardware would).
-SMEM_BYTES_PER_CORE = 512 * 1024
+# Declared per-core budgets, set from compiles of the kernels for a
+# described TPU v5e ("TPU v5 lite", jax.experimental.topologies, v5e:2x2):
+#   * SMEM is 1 MiB: a 64k-int32 prefetch table compiles, a 256k-int32 one
+#     is refused ("Used 1.00M of 1.00M smem").
+#   * VMEM is 128 MiB: a 129 MiB scratch is refused ("would exceed memory
+#     (size=134217728)"). The kernels keep to a 16 MiB per-launch budget
+#     (block + double-buffered gather + panels), far below it.
+# CPU interpret runs enforce the same budgets the chip would.
+SMEM_BYTES_PER_CORE = 1024 * 1024
 VMEM_BYTES_PER_CORE = 16 * 1024 * 1024
 
 # Default kernel block shapes: BR rows per grid step, BF feature lanes.
@@ -65,6 +72,12 @@ def ell_chunk_rows(k: int, block_rows: int = DEFAULT_BR,
     return chunk - chunk % block_rows
 
 
+def lane_tiles(width: int) -> int:
+    """``width`` rounded up to whole 128-lane tiles (the kernels zero-pad
+    gathered rows to this width)."""
+    return -(-width // DEFAULT_BF) * DEFAULT_BF
+
+
 def ell_launch_usage(rows: int, k: int, feat: int, *,
                      block_rows: int = DEFAULT_BR,
                      block_feat: int = DEFAULT_BF,
@@ -72,7 +85,7 @@ def ell_launch_usage(rows: int, k: int, feat: int, *,
                      weighted: bool = False) -> Dict[str, int]:
     """Static SMEM/VMEM bytes of one (chunked) SpMM ELL launch."""
     launch_rows = min(rows, ell_chunk_rows(k, block_rows))
-    bf = block_feat if feat % block_feat == 0 else feat
+    bf = min(block_feat, lane_tiles(feat))
     smem = launch_rows * k * _I32                      # prefetched table
     vmem = (DOUBLE_BUFFER_SLOTS * block_rows * bf * dtype_bytes  # gather buf
             + block_rows * bf * dtype_bytes)                     # out block
@@ -98,8 +111,8 @@ def attn_launch_usage(rows: int, k: int, heads: int, feat: int, *,
     softmax statistics as extra per-head output blocks.
     """
     launch_rows = min(rows, ell_chunk_rows(k, block_rows))
-    bf = block_feat if feat % block_feat == 0 else feat
-    al = heads * logit_dim
+    bf = min(block_feat, lane_tiles(feat))
+    al = lane_tiles(heads * logit_dim)
     smem = launch_rows * k * _I32
     vmem = (DOUBLE_BUFFER_SLOTS * block_rows * bf * dtype_bytes   # z gather
             + DOUBLE_BUFFER_SLOTS * block_rows * al * dtype_bytes  # alpha

@@ -119,6 +119,7 @@ def _flash_attention_pallas_impl(q: jnp.ndarray, k: jnp.ndarray,
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="_flash_kernel",
     )(qr, kr, vr)
     return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
 

@@ -85,6 +85,7 @@ def _grouped_matmul_pallas_impl(x: jnp.ndarray, w: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         interpret=interpret,
+        name="_gmm_kernel",
     )(tile_group, x, w)
 
 

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import use_pallas
-from repro.kernels.grouped_matmul import ref
 from repro.kernels.grouped_matmul.grouped_matmul import grouped_matmul_pallas
 
 
@@ -26,8 +25,7 @@ def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray,
                    interpret: bool = False) -> jnp.ndarray:
     """Grouped GEMM over group-sorted rows. Dispatches kernel or XLA oracle.
 
-    The XLA path uses ``jax.lax.ragged_dot`` when available (native grouped
-    matmul lowering) and falls back to the gather-einsum oracle otherwise.
+    The XLA path is ``jax.lax.ragged_dot`` (native grouped matmul lowering).
     The Pallas path needs *concrete* ``group_sizes`` (row packing is a host
     shape decision); traced sizes fall back to the XLA path — same
     convention as the SpMM dispatch under tracing. The Pallas branch carries
@@ -42,10 +40,7 @@ def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray,
         return _grouped_matmul_diff(sizes, bool(interpret), x, w)
     # The named scope tags the XLA fallback for the dispatch auditor.
     with jax.named_scope("repro_oracle:grouped_matmul"):
-        try:
-            return jax.lax.ragged_dot(x, w, group_sizes.astype(jnp.int32))
-        except Exception:  # pragma: no cover - older jax
-            return ref.grouped_matmul(x, w, group_sizes)
+        return jax.lax.ragged_dot(x, w, jnp.asarray(group_sizes, jnp.int32))
 
 
 def _pack_plan(sizes: Tuple[int, ...], block_m: int = 128

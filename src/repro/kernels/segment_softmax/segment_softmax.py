@@ -61,6 +61,7 @@ def _segment_softmax_pallas_impl(values: jnp.ndarray, mask: jnp.ndarray, *,
         out_specs=pl.BlockSpec((block_rows, k), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows + pad, k), values.dtype),
         interpret=interpret,
+        name="_segment_softmax_kernel",
     )(values, mask)
     return out[:rows] if pad else out
 

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import budgets as hw_budgets, use_pallas
+from repro.kernels import budgets as hw_budgets, interpret_default, use_pallas
 from repro.kernels.budgets import MAX_PREFETCH_ELEMS  # noqa: F401  re-export
 from repro.kernels.spmm import ref
 from repro.kernels.spmm.spmm import spmm_ell_pallas
@@ -228,8 +228,6 @@ def _spmm_ell_pallas_chunked(ell_idx: jnp.ndarray,
     Calls the module-global ``spmm_ell_pallas`` (not a captured reference) so
     test spies that monkeypatch the ops attribute still observe every launch.
     """
-    feat = x.shape[1]
-    bf = 128 if feat % 128 == 0 else feat
     rows, k = ell_idx.shape
     from repro.kernels.spmm.spmm import DEFAULT_BR
     # Launch-time backstop against the *declared* hardware budgets (the
@@ -240,13 +238,13 @@ def _spmm_ell_pallas_chunked(ell_idx: jnp.ndarray,
     chunk -= chunk % DEFAULT_BR
     if rows <= chunk:
         return spmm_ell_pallas(ell_idx, ell_w, x, reduce=reduce,
-                               block_feat=bf, interpret=interpret)
+                               interpret=interpret)
     outs = []
     for lo in range(0, rows, chunk):
         hi = min(lo + chunk, rows)
         outs.append(spmm_ell_pallas(
             ell_idx[lo:hi], None if ell_w is None else ell_w[lo:hi], x,
-            reduce=reduce, block_feat=bf, interpret=interpret))
+            reduce=reduce, interpret=interpret))
     return jnp.concatenate(outs, axis=0)
 
 
@@ -338,7 +336,7 @@ def spmm_ell(ell_idx: jnp.ndarray, ell_w: Optional[jnp.ndarray],
     if not take_pallas:
         return ref.spmm_ell(ell_idx, ell_w, x, reduce=reduce)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     return _spmm_ell_pallas_diff(reduce, bool(interpret), ell_idx, ell_w, x)
 
 

@@ -33,9 +33,12 @@ from repro.configs import ARCH_IDS, canonical, get_config
 from repro.distributed import sharding as shard_lib
 from repro.launch import hlo_analysis, jaxpr_stats
 from repro.launch import specs as specs_lib
-from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
-                               make_production_mesh)
+from repro.launch.mesh import chip_peaks, make_production_mesh
 from repro.train import steps as steps_lib
+
+# The production meshes are TPU v5e pods; the dry run compiles on host
+# devices, so the target chip is named here rather than read off a device.
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 _DTYPE_BYTES = {
     "f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "s8": 1, "u8": 1,
@@ -135,9 +138,10 @@ def roofline_terms(flops: float, bytes_acc: float, coll: dict,
     local_link_bytes = sum(
         v["bytes"] * ring.get(name, 1.0) for name, v in coll.items())
     coll_bytes_global = local_link_bytes * n_chips
-    t_compute = flops / n_chips / PEAK_FLOPS_BF16
-    t_memory = bytes_acc / n_chips / HBM_BW
-    t_coll = coll_bytes_global / n_chips / ICI_BW
+    peaks = chip_peaks(TARGET_DEVICE_KIND)
+    t_compute = flops / n_chips / peaks["peak_flops_bf16"]
+    t_memory = bytes_acc / n_chips / peaks["hbm_bytes_per_s"]
+    t_coll = coll_bytes_global / n_chips / peaks["ici_bytes_per_s_per_link"]
     dom = max(("compute", t_compute), ("memory", t_memory),
               ("collective", t_coll), key=lambda kv: kv[1])[0]
     return {

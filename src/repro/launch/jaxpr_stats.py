@@ -24,6 +24,8 @@ import jax
 import numpy as np
 from jax import core
 
+from repro.kernels import pallas_kernel_name
+
 FLOP_REPORT_KEYS = ("dot_flops", "conv_flops", "elementwise_flops",
                     "pallas_flops", "total_flops", "major_bytes",
                     "collective_bytes", "while_warning")
@@ -155,12 +157,12 @@ def _gat_ell_cost(eqn):
 
 
 def _attn_ell_cost(eqn):
-    """Carry-mode typed-attention launch: outs = (acc (R,H*F), m, l (R,H))."""
+    """Carry-mode typed-attention launch: outs = (acc (R,H*F), m, l (H,R,1))."""
     ins, outs = _avals(eqn)
     table = _ell_table(ins)
     r, k = table.shape
     acc, m = outs[0], outs[1]
-    heads = m.shape[1]
+    heads = m.shape[0]
     hf = acc.shape[-1]
     # adst is the (R, H*LD) float operand row-aligned with the table
     adst = next((a for a in ins
@@ -218,9 +220,7 @@ _PALLAS_COSTS = {
 
 def _pallas_cost(eqn):
     """(flops, bytes) of one pallas_call eqn, keyed off the kernel name."""
-    info = eqn.params.get("name_and_src_info")
-    kernel = getattr(info, "name", None) or eqn.params.get("name", "")
-    fn = _PALLAS_COSTS.get(kernel)
+    fn = _PALLAS_COSTS.get(pallas_kernel_name(eqn))
     if fn is not None:
         try:
             return fn(eqn)

@@ -86,7 +86,22 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     return make_mesh(shape, axes)
 
 
-# TPU v5e hardware constants (per chip) used by the roofline analysis.
-PEAK_FLOPS_BF16 = 197e12      # FLOP/s
-HBM_BW = 819e9                # bytes/s
-ICI_BW = 50e9                 # bytes/s per link
+# Per-chip peaks for roofline analysis, keyed by ``device.device_kind``.
+# "TPU v5 lite" is TPU v5e (Google Cloud documentation, "TPU v5e"): 197
+# TFLOP/s bf16, HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip
+# interconnect over 4 links (50 GB/s each).
+CHIP_PEAKS = {
+    "TPU v5 lite": {"peak_flops_bf16": 197e12, "hbm_bytes_per_s": 819e9,
+                    "ici_bytes_per_s_per_link": 50e9},
+}
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """Published peaks of one chip; an unknown ``device_kind`` raises."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak table for device_kind {device_kind!r}; known: "
+            f"{sorted(CHIP_PEAKS)}. Add its published peaks to "
+            f"repro.launch.mesh.CHIP_PEAKS with their source.") from None

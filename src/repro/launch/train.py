@@ -18,7 +18,7 @@ from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax.flatten_util import ravel_pytree
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.analysis.retrace import RetraceSentinel
@@ -128,15 +128,17 @@ class MeshTrainer:
                 shard = jax.tree_util.tree_map(lambda l: l[0], stacked)
                 grads, loss_sum, weight = _local_grads(state.params, shard)
                 # one fused all-reduce: the grad pytree + the two loss
-                # scalars reduce in a single psum
-                grads, loss_sum, weight = jax.lax.psum(
-                    (grads, loss_sum, weight), axis)
+                # scalars reduce in a single psum of one flat vector (a
+                # psum of a pytree binds one collective per leaf)
+                flat, unflatten = ravel_pytree((grads, loss_sum, weight))
+                grads, loss_sum, weight = unflatten(
+                    jax.lax.psum(flat, axis))
                 return _finish(state, grads, loss_sum, weight)
 
-            return shard_map(_shard_body, self.mesh,
-                             in_specs=(P(), P(axis)),
-                             out_specs=(P(), P()),
-                             check_rep=False)
+            return jax.shard_map(_shard_body, mesh=self.mesh,
+                                 in_specs=(P(), P(axis)),
+                                 out_specs=(P(), P()),
+                                 check_vma=False)
 
         def _shard_body_compressed(state, stacked, residual):
             shard = jax.tree_util.tree_map(lambda l: l[0], stacked)
@@ -150,10 +152,10 @@ class MeshTrainer:
             residual = jax.tree_util.tree_map(lambda l: l[None], new_res)
             return state, metrics, residual
 
-        return shard_map(_shard_body_compressed, self.mesh,
-                         in_specs=(P(), P(axis), P(axis)),
-                         out_specs=(P(), P(), P(axis)),
-                         check_rep=False)
+        return jax.shard_map(_shard_body_compressed, mesh=self.mesh,
+                             in_specs=(P(), P(axis), P(axis)),
+                             out_specs=(P(), P(), P(axis)),
+                             check_vma=False)
 
     # ---- data/state placement ----
     def _check_stacked(self, batch):

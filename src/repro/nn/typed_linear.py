@@ -18,10 +18,10 @@ from __future__ import annotations
 
 from typing import List, Optional, Sequence, Union
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import interpret_default
 from repro.kernels.grouped_matmul import ops as gmm_ops
 
 
@@ -45,7 +45,7 @@ def grouped_apply(chunks: Sequence[jnp.ndarray],
     if not isinstance(weights, jnp.ndarray):
         weights = jnp.stack(list(weights))
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     out = gmm_ops.grouped_matmul(
         jnp.concatenate(list(chunks), axis=0), weights, sizes,
         force_pallas=force_pallas, interpret=interpret)

@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.launch import hlo_analysis, jaxpr_stats
 
@@ -73,6 +74,7 @@ def test_batched_dot_general():
 def test_pallas_spmm_cost_exact():
     """pallas_call eqns are costed by the per-kernel analytic model (the
     kernel body is opaque to the generic eqn walk)."""
+    from repro.kernels.budgets import lane_tiles
     from repro.kernels.spmm import ops as spmm_ops
 
     rng = np.random.default_rng(3)
@@ -88,7 +90,8 @@ def test_pallas_spmm_cost_exact():
     x = jax.ShapeDtypeStruct((n, f), jnp.float32)
     stats = jaxpr_stats.step_stats(fwd, x)
     r, k = ell_idx.shape
-    assert stats["pallas_flops"] == 2 * r * k * f
+    # the launch runs at the lane-padded width: F zero-padded to 128 lanes
+    assert stats["pallas_flops"] == 2 * r * k * lane_tiles(f)
     assert stats["pallas_flops"] <= stats["total_flops"]  # + glue eltwise
     assert stats["major_bytes"] >= r * k * 4  # at least the prefetch table
 
@@ -109,6 +112,38 @@ def test_pallas_cost_generic_fallback():
         fwd, jax.ShapeDtypeStruct((8, 16), jnp.float32))
     assert stats["pallas_flops"] == 8 * 16
     assert stats["major_bytes"] == 2 * 8 * 16 * 4
+
+
+@pytest.mark.parametrize("interpret", [True, False])
+def test_audit_names_launches_and_counts_interpret(interpret):
+    """Launches are keyed by ``name=``, else by the kernel function's name,
+    and interpret-mode launches are counted (a chip run must show 0)."""
+    from jax.experimental import pallas as pl
+
+    from repro.analysis import audit_jaxpr
+
+    def double_kernel(x_ref, o_ref):
+        o_ref[...] = x_ref[...] * 2
+
+    def fwd(x):
+        out = jax.ShapeDtypeStruct((8, 128), jnp.float32)
+        named = pl.pallas_call(double_kernel, out_shape=out,
+                               interpret=interpret, name="_named_kernel")(x)
+        return pl.pallas_call(double_kernel, out_shape=out,
+                              interpret=interpret)(named)
+
+    report = audit_jaxpr(jax.make_jaxpr(fwd)(
+        jax.ShapeDtypeStruct((8, 128), jnp.float32)))
+    assert report.kernel_launches == {"_named_kernel": 1, "double_kernel": 1}
+    assert report.interpret_launches == (2 if interpret else 0)
+
+
+def test_chip_peaks_unknown_kind_raises():
+    from repro.launch.mesh import chip_peaks
+
+    assert chip_peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
+    with pytest.raises(KeyError, match="no peak table"):
+        chip_peaks("cpu")
 
 
 SAMPLE_HLO = """
@@ -158,9 +193,7 @@ def test_sharding_specs_divisible_for_all_archs():
 
     for mesh_shape, axes in (((16, 16), ("data", "model")),
                              ((2, 16, 16), ("pod", "data", "model"))):
-        # jax 0.4.37 AbstractMesh signature: a ((name, size), ...) tuple
-        # (newer jax takes (shape, axis_names) — pass the portable form).
-        mesh = AbstractMesh(tuple(zip(axes, mesh_shape)))
+        mesh = AbstractMesh(mesh_shape, axes)
         for arch in ARCH_IDS:
             cfg = get_config(arch)
             params = specs_lib.abstract_params(cfg)

@@ -305,7 +305,6 @@ class TestCompression:
     def test_compressed_allreduce_matches_psum(self, rng):
         # topk at ratio 1.0 is lossless: the all_gather+decompress-sum path
         # must agree with a plain psum to <= 1e-5
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = data_parallel_mesh(4)
@@ -318,9 +317,9 @@ class TestCompression:
                 lg, {"w": r[0]}, axis_name="data", method="topk", ratio=1.0)
             return summed["w"]
 
-        got = jax.jit(shard_map(
-            body, mesh, in_specs=(P("data"), P("data")),
-            out_specs=P(), check_rep=False))(g, r)
+        got = jax.jit(jax.shard_map(
+            body, mesh=mesh, in_specs=(P("data"), P("data")),
+            out_specs=P(), check_vma=False))(g, r)
         np.testing.assert_allclose(np.asarray(got),
                                    np.asarray(g.sum(axis=0)),
                                    rtol=1e-5, atol=1e-5)

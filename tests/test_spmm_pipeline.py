@@ -126,10 +126,9 @@ def test_kernel_sweep_slow(rng, reduce, shape):
     ell = rng.integers(-1, n, (rows, k)).astype(np.int32)
     w = jnp.asarray(rng.standard_normal((rows, k)).astype(np.float32))
     x = jnp.asarray(rng.standard_normal((n, f)).astype(np.float32))
-    bf = 128 if f % 128 == 0 else f
     a = spmm_ref.spmm_ell(jnp.asarray(ell), w, x, reduce=reduce)
     b = spmm_ell_pallas(jnp.asarray(ell), w, x, reduce=reduce,
-                        block_feat=bf, interpret=True)
+                        interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
                                atol=1e-5)
 
