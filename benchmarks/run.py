@@ -3,9 +3,7 @@
 Prints ``name,us_per_call,derived`` CSV rows (see per-module docstrings for
 protocols). Every suite runs in this one process, so on a TPU the process
 that holds the chip is the one that uses it. Any suite that raises makes the
-run exit non-zero. The dry-run roofline table is not part of this run:
-``python -m repro.launch.dryrun --all`` then ``python -m
-benchmarks.roofline``.
+run exit non-zero.
 """
 
 from __future__ import annotations

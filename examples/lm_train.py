@@ -4,7 +4,7 @@ a few hundred steps on the synthetic pipeline, with checkpoints + resume.
 Default is a ~19M-param model x 200 steps (CPU-friendly). ``--big`` switches
 to a ~110M-param model (same code path; slower on this container). On TPU
 the identical driver runs the full assigned configs under the production
-mesh (see repro.launch.train / repro.launch.dryrun).
+mesh (see repro.launch.train).
 
 Run:  PYTHONPATH=src python examples/lm_train.py [--steps 200] [--big]
 """

@@ -10,6 +10,7 @@ Layers (bottom-up):
   distributed/  sharding rules, checkpointing, elastic re-meshing
   launch/       production meshes, multi-pod dry-run, drivers
   configs/      assigned architecture configs (+ reduced smoke variants)
+  trace.py      the program's spans and counters (off by default)
 """
 
 __version__ = "2.0.0"

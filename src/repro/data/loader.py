@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace
 from repro.core.edge_index import EdgeIndex
 from repro.data.feature_store import FeatureStore
 from repro.data.graph_store import DEFAULT_ETYPE, GraphStore
@@ -154,6 +155,17 @@ def stack_batches(batches: List[Batch]) -> Batch:
 
 _SKIP = object()  # sentinel: a batch dropped by on_batch_error="skip"
 
+
+def _real_edges(sample) -> int:
+    """Sampled edges that are real (edge id >= 0, not budget padding) in a
+    ``_stage_sample`` result, over its shards and edge types."""
+    if "parts" in sample:
+        return sum(_real_edges(p) for p in sample["parts"])
+    edge = sample["out"].edge
+    leaves = edge.values() if isinstance(edge, dict) else [edge]
+    return sum(int(np.count_nonzero(e >= 0)) for e in leaves)
+
+
 _BATCH_ERROR_MODES = ("raise", "retry", "skip")
 
 
@@ -194,6 +206,13 @@ class _PrefetchLoader:
     The pipelined producer applies the *same* policy with the same
     counters: a chain that failed in flight consumed attempt 0, and the
     remaining attempts re-run sequentially at its reassembly slot.
+
+    With the program's tracer on (``repro.trace.enable()``) each stage runs
+    under a span (``loader.sample``/``gather``/``pack``, tagged with the
+    batch's index), the producer's wait on a full queue under
+    ``loader.queue_put`` and the consumer's under ``loader.wait``; the
+    counters ``loader.sampled_edges`` and ``loader.put_bytes`` add each
+    batch's real sampled edges and array bytes.
     """
 
     input_nodes: np.ndarray
@@ -220,9 +239,30 @@ class _PrefetchLoader:
         raise NotImplementedError
 
     def _make_batch(self, seeds: np.ndarray,
-                    seed_time: Optional[np.ndarray]):
-        sample = self._stage_sample(seeds, seed_time)
-        return self._stage_pack(sample, self._stage_gather(sample))
+                    seed_time: Optional[np.ndarray], index: int):
+        sample = self._sample(seeds, seed_time, index)
+        return self._pack(sample, self._gather(sample, index), index)
+
+    # ---- the stages under the program's spans (``repro.trace``); ``index``
+    # is the batch's place in the epoch's seed order ----
+    def _sample(self, seeds, seed_time, index: int):
+        with trace.span("loader.sample", batch=index):
+            sample = self._stage_sample(seeds, seed_time)
+        if trace.enabled():
+            trace.count("loader.sampled_edges", _real_edges(sample))
+        return sample
+
+    def _gather(self, sample, index: int):
+        with trace.span("loader.gather", batch=index):
+            return self._stage_gather(sample)
+
+    def _pack(self, sample, gather, index: int):
+        with trace.span("loader.pack", batch=index):
+            batch = self._stage_pack(sample, gather)
+        if trace.enabled():
+            trace.count("loader.put_bytes", sum(
+                int(a.nbytes) for a in jax.tree_util.tree_leaves(batch)))
+        return batch
 
     def _init_policy(self, on_batch_error: str, batch_retries: int):
         if on_batch_error not in _BATCH_ERROR_MODES:
@@ -253,7 +293,7 @@ class _PrefetchLoader:
         self.health["batches"] += 1
         self.health["degraded_rows"] += self._degraded_count(batch)
 
-    def _make_batch_guarded(self, seeds, seed_time, abort=None):
+    def _make_batch_guarded(self, seeds, seed_time, index, abort=None):
         """Apply ``on_batch_error`` around the full batch chain.
 
         Returns the batch, or ``_SKIP`` when the policy drops it. ``abort``
@@ -263,13 +303,13 @@ class _PrefetchLoader:
         if not hasattr(self, "health"):
             self._init_policy(self.on_batch_error, self.batch_retries)
         try:
-            batch = self._make_batch(seeds, seed_time)
+            batch = self._make_batch(seeds, seed_time, index)
         except StoreError as exc:
-            return self._finish_policy(seeds, seed_time, exc, abort)
+            return self._finish_policy(seeds, seed_time, index, exc, abort)
         self._count_success(batch)
         return batch
 
-    def _finish_policy(self, seeds, seed_time, first_exc, abort):
+    def _finish_policy(self, seeds, seed_time, index, first_exc, abort):
         """Policy attempts 1..N after attempt 0 raised ``first_exc``.
 
         Shared by the sequential path and the pipelined reassembly (where
@@ -285,7 +325,7 @@ class _PrefetchLoader:
             if abort is not None and abort():
                 break
             try:
-                batch = self._make_batch(seeds, seed_time)
+                batch = self._make_batch(seeds, seed_time, index)
             except StoreError as exc:
                 last = exc
                 if attempt + 1 < attempts:
@@ -336,18 +376,19 @@ class _PrefetchLoader:
 
     # ---- batch production (sequential or stage-pipelined) ----
     def _produce(self, abort=None):
-        """Yield policy-guarded batches in seed-batch order."""
+        """Yield ``(index, batch)``: policy-guarded batches in seed-batch
+        order, each with its place in that order."""
         if not hasattr(self, "health"):
             self._init_policy(self.on_batch_error, self.batch_retries)
         if self.pipeline_depth > 1:
             yield from self._produce_pipelined(abort)
             return
-        for seeds, t in self._seed_batches():
+        for index, (seeds, t) in enumerate(self._seed_batches()):
             if abort is not None and abort():
                 return
-            batch = self._make_batch_guarded(seeds, t, abort=abort)
+            batch = self._make_batch_guarded(seeds, t, index, abort=abort)
             if batch is not _SKIP:
-                yield batch
+                yield index, batch
 
     def _produce_pipelined(self, abort=None):
         """Stage-pipelined production with ordered reassembly.
@@ -372,37 +413,38 @@ class _PrefetchLoader:
         depth = self.pipeline_depth
         pool = ThreadPoolExecutor(max_workers=depth,
                                   thread_name_prefix="loader-stage")
-        inflight: deque = deque()  # (seeds, t, sample, Future | StoreError)
-        seed_iter = self._seed_batches()
+        # (index, seeds, t, sample, Future | StoreError)
+        inflight: deque = deque()
+        seed_iter = enumerate(self._seed_batches())
         exhausted = False
         try:
             while True:
                 while not exhausted and len(inflight) < depth:
                     try:
-                        seeds, t = next(seed_iter)
+                        index, (seeds, t) = next(seed_iter)
                     except StopIteration:
                         exhausted = True
                         break
                     try:
-                        sample = self._stage_sample(seeds, t)
+                        sample = self._sample(seeds, t, index)
                     except StoreError as exc:  # sampling itself can fetch
-                        inflight.append((seeds, t, None, exc))
+                        inflight.append((index, seeds, t, None, exc))
                     else:
-                        inflight.append((seeds, t, sample, pool.submit(
-                            self._stage_gather, sample)))
+                        inflight.append((index, seeds, t, sample, pool.submit(
+                            self._gather, sample, index)))
                 if not inflight:
                     return
-                seeds, t, sample, head = inflight.popleft()
+                index, seeds, t, sample, head = inflight.popleft()
                 try:
                     if isinstance(head, StoreError):
                         raise head
-                    batch = self._stage_pack(sample, head.result())
+                    batch = self._pack(sample, head.result(), index)
                 except StoreError as exc:
-                    batch = self._finish_policy(seeds, t, exc, abort)
+                    batch = self._finish_policy(seeds, t, index, exc, abort)
                 else:
                     self._count_success(batch)
                 if batch is not _SKIP:
-                    yield batch
+                    yield index, batch
                 if abort is not None and abort():
                     return
         finally:
@@ -414,7 +456,8 @@ class _PrefetchLoader:
             # pipeline_depth > 1 gathers still overlap on the worker pool
             gen = self._produce()
             try:
-                yield from gen
+                for _, batch in gen:
+                    yield batch
             finally:
                 gen.close()  # deterministic worker-pool teardown
             return
@@ -429,10 +472,11 @@ class _PrefetchLoader:
             # would never enqueue the sentinel and deadlock `q.get()`.
             gen = self._produce(abort=abandoned.is_set)
             try:
-                for batch in gen:
+                for index, batch in gen:
                     if abandoned.is_set():
                         return
-                    q.put(batch)
+                    with trace.span("loader.queue_put", batch=index):
+                        q.put(batch)
             except BaseException as exc:  # noqa: BLE001 - re-raised below
                 q.put(exc)
                 return
@@ -445,7 +489,8 @@ class _PrefetchLoader:
         th.start()
         try:
             while True:
-                item = q.get()
+                with trace.span("loader.wait"):
+                    item = q.get()
                 if item is stop:
                     break
                 if isinstance(item, BaseException):

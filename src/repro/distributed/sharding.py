@@ -293,8 +293,9 @@ def cache_shardings(mesh: Mesh, cache_shape, batch: int) -> Any:
 # in §Perf iteration 1: fully-replicated global-batch attention logits being
 # all-reduced per block). Production JAX frameworks pin every major
 # activation with with_sharding_constraint; these hooks do the same. The
-# context is set at trace time (dryrun/train drivers); without it the model
-# is constraint-free (the paper-faithful baseline + single-device tests).
+# training entry points set the context at trace time; without it the
+# model is constraint-free (the paper-faithful baseline + single-device
+# tests).
 
 _ACT_CTX: Optional[Tuple[Mesh, str]] = None
 

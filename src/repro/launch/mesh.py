@@ -1,12 +1,12 @@
 """Mesh construction on the modern ``jax.sharding.Mesh`` API.
 
 Functions, not module-level constants: importing this module never touches
-jax device state (device count is locked at first jax init; only
-``dryrun.py`` forces 512 host devices, and CPU testing of the data-parallel
-path forces a small count via ``XLA_FLAGS`` — see :func:`host_device_flag`).
+jax device state (device count is locked at first jax init; CPU testing
+of the data-parallel path forces a small count via ``XLA_FLAGS`` — see
+:func:`host_device_flag`).
 
 The data-parallel GNN scale-out (PR 10) builds 1-D ``("data",)`` meshes via
-:func:`data_parallel_mesh`; the LM dry-run keeps its 2-D/3-D production
+:func:`data_parallel_mesh`; the LM meshes keep their 2-D/3-D production
 shapes. All constructors go through :func:`make_mesh`, which builds a
 ``jax.sharding.Mesh`` from an explicit device array — the stale
 ``jax.make_mesh``-era helpers required the mesh to cover *every* visible
@@ -17,7 +17,7 @@ forced-8-device host process.
 from __future__ import annotations
 
 import math
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
 import jax
 import numpy as np
@@ -77,13 +77,6 @@ def data_parallel_mesh(num_devices: Optional[int] = None,
 def make_local_mesh(data: int = 1, model: int = 1) -> Mesh:
     """Small 2-D mesh over however many (host) devices exist — tests only."""
     return make_mesh((data, model), ("data", "model"))
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    """16x16 single pod (256 chips) or 2x16x16 two-pod (512 chips)."""
-    shape: Tuple[int, ...] = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
 
 
 # Per-chip peaks for roofline analysis, keyed by ``device.device_kind``.

@@ -18,6 +18,7 @@ from typing import Any, Callable, Dict, Iterator, Optional
 import jax
 import numpy as np
 
+from repro import trace
 from repro.analysis.retrace import RetraceSentinel
 from repro.distributed import checkpoint as ckpt_lib
 from repro.distributed import compression as comp_lib
@@ -27,6 +28,23 @@ from repro.train import optimizer as opt_lib
 
 class SimulatedFailure(RuntimeError):
     pass
+
+
+LOADER_STAGES = ("sample", "gather", "pack")
+
+
+def _loader_stage_means() -> str:
+    """`` loader ms/batch: sample=.. gather=.. pack=..`` from the program's
+    spans (``repro.trace``) while tracing is on; empty otherwise."""
+    if not trace.enabled():
+        return ""
+    spans = trace.totals()["spans"]
+    parts = []
+    for stage in LOADER_STAGES:
+        n, seconds = spans.get(f"loader.{stage}", (0, 0.0))
+        if n:
+            parts.append(f"{stage}={1e3 * seconds / n:.1f}")
+    return f" loader ms/batch: {' '.join(parts)}" if parts else ""
 
 
 def train_loop(state: opt_lib.TrainState,
@@ -92,7 +110,7 @@ def train_loop(state: opt_lib.TrainState,
             health = ("" if loader is None or not hasattr(loader, "health")
                       else f" health={dict(loader.health)}")
             log_fn(f"step {step + 1}: loss={loss:.4f} "
-                   f"({dt * 1e3:.0f} ms){health}")
+                   f"({dt * 1e3:.0f} ms){health}{_loader_stage_means()}")
         if ckpt_dir is not None and (step + 1) % ckpt_every == 0:
             if pending is not None:
                 pending.join()
