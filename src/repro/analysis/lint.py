@@ -72,7 +72,7 @@ PALLAS_CALL_ALLOWLIST: Set[str] = {"use_pallas", "forward_only_pallas"}
 HOST_PACKING_FUNCS: Dict[str, Set[str]] = {
     "repro/kernels/spmm/ops.py": {
         "_ell_positions", "csr_to_ell", "csr_to_ell_bucketed",
-        "csr_to_ell_static", "ell_layout_from_bounds"},
+        "csr_to_ell_static", "ell_layout_from_bounds", "ell_row_ranges"},
     "repro/kernels/grouped_matmul/ops.py": {"_pack_plan"},
     "repro/data/sampler.py": {"static_slot_bounds"},
     "repro/data/hetero_sampler.py": {

@@ -60,6 +60,14 @@ class EdgeIndex:
                      weights directly — and a layer-trimmed cache keeps
                      serving them, because kept slots reference kept (prefix)
                      edges only.
+      _ell_ranges:   static per-bucket row ranges of a static-layout ``_ell``
+                     — ``((lo, hi), ...)`` ascending runs of the bucket's
+                     real rows, or ``None`` for a bucket whose rows do not
+                     ascend — set from the layout by
+                     :meth:`from_coo_prefilled`. Pytree aux: batches packed
+                     against one layout share it, so they share a trace.
+                     Layer-wise trimming slices each bucket to the rows it
+                     keeps from these (``repro.core.trim``).
     """
 
     data: jnp.ndarray
@@ -71,19 +79,21 @@ class EdgeIndex:
     _csc: Optional[Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]] = None
     _ell: Optional[Tuple] = None
     _ell_t: Optional[Tuple] = None
+    _ell_ranges: Optional[Tuple] = None
 
     # ------------------------------------------------------------------ pytree
     def tree_flatten(self):
         children = (self.data, self._csr, self._csc, self._ell, self._ell_t)
         aux = (self.num_src_nodes, self.num_dst_nodes, self.sort_order,
-               self.is_undirected)
+               self.is_undirected, self._ell_ranges)
         return children, aux
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         data, csr, csc, ell, ell_t = children
-        ns, nd, so, undirected = aux
-        return cls(data, ns, nd, so, undirected, csr, csc, ell, ell_t)
+        ns, nd, so, undirected, ell_ranges = aux
+        return cls(data, ns, nd, so, undirected, csr, csc, ell, ell_t,
+                   ell_ranges)
 
     # ------------------------------------------------------------- constructors
     @classmethod
@@ -136,11 +146,13 @@ class EdgeIndex:
         rowptr = np.searchsorted(src[perm_r], np.arange(
             num_src_nodes + 1)).astype(np.int32)
         csr_idx = dst[perm_r]
-        ell = None
+        ell = ell_ranges = None
         if ell_layout is not None:
             ell = cls._ell_pos_to_coo(
                 spmm_ops.csr_to_ell_static(colptr, csc_idx, ell_layout,
                                            block_rows=block_rows), perm_c)
+            ell_ranges = tuple(spmm_ops.ell_row_ranges(rows)
+                               for rows, _ in ell_layout)
         return cls(
             jnp.asarray(np.stack([src, dst])), int(num_src_nodes),
             int(num_dst_nodes), None, False,
@@ -148,7 +160,7 @@ class EdgeIndex:
                   jnp.asarray(perm_r)),
             _csc=(jnp.asarray(colptr), jnp.asarray(csc_idx),
                   jnp.asarray(perm_c)),
-            _ell=ell)
+            _ell=ell, _ell_ranges=ell_ranges)
 
     # ----------------------------------------------------------------- accessors
     @property

@@ -10,17 +10,20 @@ never misses. This is the TPU/XLA rendition of the paper's zero-copy narrow.
 
 Trimming no longer drops a loader-prefilled static-layout ELL cache: every
 slot's in-edges come from exactly one hop (a block is the frontier exactly
-once), so the trimmed graph's ELL is the parent's with the rows of
-dropped-hop slots masked to capacity padding — a shape-stable elementwise
-``where`` that works on tracers, keeping the Pallas SpMM fast path on inner
-layers (see ``_trim_ell``). Because ``EdgeIndex`` keys ``ell_pos`` to COO
-edge order and kept slots reference only kept (prefix) edges, the masked
-cache serves *weighted* matmuls too — per-layer ``edge_weight`` slices
-gather straight through the inherited positions, no oracle detour. The
-masked cache equally serves the fused *attention* path
-(``EdgeIndex.attend``): kept rows keep their neighbor slots, dropped rows
-become capacity padding the kernel softmax masks out, so deep GATs keep
-the flash-GAT kernel on inner hops. A demand-filled *transpose* ELL
+once), so the trimmed graph's ELL is the parent's restricted to the rows of
+kept-hop slots. The layout's buckets list their rows in ascending ranges
+(``EdgeIndex._ell_ranges``, static pytree aux), so the kept rows form a
+prefix of each bucket and the trim is a static slice of it, rounded up to a
+row block — the kernels launch over the kept rows only, and their backward
+panels shrink with them (see ``_trim_ell``). A bucket with no known ranges
+(demand-filled, or a layout whose rows do not ascend) keeps its shape and
+masks the dropped rows to capacity padding instead. Because ``EdgeIndex``
+keys ``ell_pos`` to COO edge order and kept slots reference only kept
+(prefix) edges, the trimmed cache serves *weighted* matmuls too —
+per-layer ``edge_weight`` slices gather straight through the inherited
+positions, no oracle detour. It equally serves the fused *attention* path
+(``EdgeIndex.attend``): kept rows keep their neighbor slots, so deep GATs
+keep the flash-GAT kernel on inner hops. A demand-filled *transpose* ELL
 survives too (``_trim_ell_transpose`` — per-slot masking, since transpose
 rows' out-edges don't form a hop prefix), keeping reversed-flow
 (``target_to_source``) attends and transpose matmuls on the kernel.
@@ -33,8 +36,10 @@ from __future__ import annotations
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.edge_index import EdgeIndex
+from repro.kernels.budgets import DEFAULT_BR
 
 
 def trim_sizes(num_nodes_per_hop: Sequence[int],
@@ -52,27 +57,52 @@ def trim_sizes(num_nodes_per_hop: Sequence[int],
     return n_nodes, n_edges
 
 
-def _trim_ell(ell, boundary: int):
-    """Mask a static-layout bucketed ELL down to slots that keep edges.
+def _trim_ell(ell, ranges, boundary: int):
+    """Cut a static-layout bucketed ELL down to the rows that keep edges.
 
     ``boundary`` is the first slot whose in-edges are dropped (hop-``h``
     edges always point into the hop ``h-1`` block, so kept slots form a
-    prefix). Rows at/past the boundary become capacity padding (``-1`` row
-    ids, all-invalid neighbor slots) — shapes are unchanged, so this is
-    jit-stable and valid on tracer leaves. ``ell_pos`` is masked too; the
-    surviving slots' positions index the COO (BFS) edge order and point only
-    at kept prefix edges, so the trimmed cache serves weighted matmuls
-    against per-layer-sliced ``edge_weight`` vectors directly.
+    prefix of slot space). ``ranges`` holds each bucket's static row ranges
+    (``EdgeIndex._ell_ranges``, or ``None``). A bucket with ranges keeps
+    ``sum(clip(boundary - lo, 0, hi - lo))`` rows, a prefix of it: it is
+    sliced statically to that count rounded up to a ``DEFAULT_BR`` row
+    block, the rounded tail masked to capacity padding (``-1`` row ids,
+    all-invalid slots), and dropped when it keeps none. A bucket without
+    ranges keeps its shape and is masked row by row. Both are jit-stable
+    (the cut is a Python int) and valid on tracer leaves. ``ell_pos`` is
+    cut alike; the surviving slots' positions index the COO (BFS) edge
+    order and point only at kept prefix edges, so the trimmed cache serves
+    weighted matmuls against per-layer-sliced ``edge_weight`` vectors
+    directly. Returns the trimmed buckets and their ranges.
     """
     if ell is None:
-        return None
-    trimmed = []
-    for row_ids, ell_idx, ell_pos in ell:
-        keep = (row_ids >= 0) & (row_ids < boundary)
-        trimmed.append((jnp.where(keep, row_ids, -1),
-                        jnp.where(keep[:, None], ell_idx, -1),
-                        jnp.where(keep[:, None], ell_pos, -1)))
-    return tuple(trimmed)
+        return None, None
+    if ranges is None:
+        ranges = (None,) * len(ell)
+    trimmed, trimmed_ranges = [], []
+    for (row_ids, ell_idx, ell_pos), runs in zip(ell, ranges):
+        if runs is None:
+            keep = (row_ids >= 0) & (row_ids < boundary)
+        else:
+            kept = sum(min(max(boundary - lo, 0), hi - lo)
+                       for lo, hi in runs)
+            if not kept:
+                continue
+            cut = min(-(-kept // DEFAULT_BR) * DEFAULT_BR, row_ids.shape[0])
+            row_ids, ell_idx, ell_pos = (row_ids[:cut], ell_idx[:cut],
+                                         ell_pos[:cut])
+            keep = np.arange(cut) < kept if cut > kept else None
+            runs = tuple((lo, min(hi, boundary)) for lo, hi in runs
+                         if lo < boundary)
+        if keep is not None:
+            # demand-filled buckets leave their row ids unpadded
+            slots = jnp.pad(keep, (0, ell_idx.shape[0] - keep.shape[0]))
+            row_ids = jnp.where(keep, row_ids, -1)
+            ell_idx = jnp.where(slots[:, None], ell_idx, -1)
+            ell_pos = jnp.where(slots[:, None], ell_pos, -1)
+        trimmed.append((row_ids, ell_idx, ell_pos))
+        trimmed_ranges.append(runs)
+    return tuple(trimmed), tuple(trimmed_ranges)
 
 
 def _trim_ell_transpose(ell, n_edges: int):
@@ -99,13 +129,15 @@ def _trim_ell_transpose(ell, n_edges: int):
 
 def _trim_edge_index(edge_index: EdgeIndex, n_src: int, n_dst: int,
                      n_edges: int, recv_boundary: int) -> EdgeIndex:
-    """Static COO slice + ELL masks; CSR/CSC caches are dropped (their edge
+    """Static COO slice + ELL cuts; CSR/CSC caches are dropped (their edge
     dimension is data-dependent after a trim) and re-derived on demand."""
+    ell, ell_ranges = _trim_ell(edge_index._ell, edge_index._ell_ranges,
+                                recv_boundary)
     return EdgeIndex(
         edge_index.data[:, :n_edges], n_src, n_dst,
         edge_index.sort_order, edge_index.is_undirected,
-        _ell=_trim_ell(edge_index._ell, recv_boundary),
-        _ell_t=_trim_ell_transpose(edge_index._ell_t, n_edges))
+        _ell=ell, _ell_t=_trim_ell_transpose(edge_index._ell_t, n_edges),
+        _ell_ranges=ell_ranges)
 
 
 def trim_to_layer(layer: int, num_nodes_per_hop: Sequence[int],
@@ -116,8 +148,9 @@ def trim_to_layer(layer: int, num_nodes_per_hop: Sequence[int],
     Requires BFS ordering: node slots grouped by hop (seeds first), edge
     slots grouped by the hop that discovered them — exactly what
     ``repro.data.sampler`` produces. All sizes static -> jit-stable. A
-    prefilled static-layout ELL cache survives the trim (masked, see
-    ``_trim_ell``), so trimmed inner layers still hit the Pallas kernel.
+    prefilled static-layout ELL cache survives the trim (cut to the kept
+    rows, see ``_trim_ell``), so trimmed inner layers still hit the Pallas
+    kernel, over the rows they keep.
     """
     n_nodes, n_edges = trim_sizes(num_nodes_per_hop, num_edges_per_hop, layer)
     x_t = x[:n_nodes]
@@ -144,8 +177,8 @@ def trim_to_layer_hetero(
     ``num_nodes_dict``/``num_edges_dict`` are the hetero sampler's per-hop
     budgets. Each relation's edges are sliced by its own hop counts; the
     node/ELL boundaries come from its endpoint types. Per-relation
-    static-layout ELL caches survive as masked caches (the hetero fast
-    path on inner layers).
+    static-layout ELL caches survive, cut to their kept rows (the hetero
+    fast path on inner layers).
     """
     depth = len(next(iter(num_edges_dict.values())))
     keep = depth - layer

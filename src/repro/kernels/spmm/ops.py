@@ -170,6 +170,28 @@ def ell_layout_from_bounds(bounds: Sequence[Tuple[int, int, int]], *,
     return layout
 
 
+def ell_row_ranges(row_ids: np.ndarray
+                   ) -> Optional[Tuple[Tuple[int, int], ...]]:
+    """A layout bucket's real rows as ascending ``((lo, hi), ...)`` runs.
+
+    ``None`` unless the real rows (``>= 0``) come first and strictly
+    ascend — then, and only then, the rows below any boundary form a
+    prefix of the bucket, which is what lets a layer trim slice it.
+    :func:`ell_layout_from_bounds` builds such buckets (ascending ranges,
+    ``-1`` padding last).
+    """
+    rows = np.asarray(row_ids)
+    real = rows[:int(np.count_nonzero(rows >= 0))]
+    if (real < 0).any() or (np.diff(real) <= 0).any():
+        return None
+    if not real.size:
+        return ()
+    cut = np.nonzero(np.diff(real) != 1)[0] + 1
+    starts = real[np.r_[0, cut]]
+    stops = real[np.r_[cut - 1, real.size - 1]] + 1
+    return tuple((int(lo), int(hi)) for lo, hi in zip(starts, stops))
+
+
 def csr_to_ell_static(indptr: np.ndarray, indices: np.ndarray,
                       layout: Sequence[Tuple[np.ndarray, int]], *,
                       block_rows: int = 8) -> List[EllBucket]:

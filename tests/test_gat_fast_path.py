@@ -17,6 +17,8 @@ dispatch, trimmed deep GATs, and a slow-marked parity sweep across the
 bucketed K ladder.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -321,9 +323,12 @@ def test_hetero_gat_per_relation_fused(rng, monkeypatch):
 
 # -------------------------------------------------------------------- trim
 def test_deep_gat_trim_keeps_kernel_and_seed_outputs(rng, monkeypatch):
-    """Layer-wise trimming of a deep GAT: inner hops keep the fused kernel
-    (masked static-layout ELL) and seed representations are unchanged."""
+    """Layer-wise trimming of a deep GAT: inner hops keep the fused kernel,
+    launched over only the static-layout ELL rows each layer keeps, and
+    seed representations and gradients are unchanged."""
     monkeypatch.setenv("REPRO_USE_PALLAS", "1")
+    monkeypatch.setattr(attn_ops, "MAX_PREFETCH_ELEMS", 64)  # 16-row chunks
+    chunk = 16
     n, e, feat = 300, 2400, 12
     data = Data(x=rng.standard_normal((n, feat)).astype(np.float32),
                 edge_index=np.stack(_random_graph(rng, n, e)))
@@ -336,17 +341,91 @@ def test_deep_gat_trim_keeps_kernel_and_seed_outputs(rng, monkeypatch):
     calls = _spy(monkeypatch, attn_ops, "gat_ell_pallas")
     full = model.apply(params, batch.x, batch.edge_index)
     full_calls = len(calls)
-    assert full_calls, "untrimmed GAT batch missed the fused kernel"
+    table = batch.edge_index._ell
+    assert full_calls == 3 * sum(-(-int(r.shape[0]) // chunk)
+                                 for r, _, _ in table), \
+        "untrimmed GAT batch missed the fused kernel"
     del calls[:]
-    trim = model.apply(params, batch.x, batch.edge_index,
-                       num_sampled_nodes_per_hop=batch.num_sampled_nodes,
-                       num_sampled_edges_per_hop=batch.num_sampled_edges,
-                       trim=True)
-    assert len(calls) == full_calls, \
-        "trimmed inner GAT layers fell off the fused kernel path"
+    trim_kw = dict(num_sampled_nodes_per_hop=batch.num_sampled_nodes,
+                   num_sampled_edges_per_hop=batch.num_sampled_edges,
+                   trim=True)
+    trim = model.apply(params, batch.x, batch.edge_index, **trim_kw)
+    # layer l keeps the rows below its receiving boundary, a prefix of
+    # each bucket, cut to a multiple of 8 rows and launched chunk by chunk
+    want = 0
+    for layer in range(3):
+        recv = int(sum(batch.num_sampled_nodes[:3 - layer]))
+        for r, _, _ in table:
+            r = np.asarray(r)
+            kept = int(((r >= 0) & (r < recv)).sum())
+            want += -(-(-(-kept // 8) * 8) // chunk)
+    assert len(calls) == want < full_calls, \
+        "trimmed GAT layers did not launch over exactly their kept rows"
     np.testing.assert_allclose(
         np.asarray(full[batch.seed_slots]),
         np.asarray(trim[batch.seed_slots]), rtol=1e-3, atol=1e-4)
+
+    def seed_loss(p, **kw):
+        out = model.apply(p, batch.x, batch.edge_index, **kw)
+        return (out[batch.seed_slots] ** 2).sum()
+
+    g_full = jax.grad(seed_loss)(params)
+    g_trim = jax.grad(functools.partial(seed_loss, **trim_kw))(params)
+    jax.tree_util.tree_map(
+        lambda a, b_: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b_), rtol=1e-3, atol=1e-4),
+        g_full, g_trim)
+
+
+def test_trimmed_gat_step_compiles_once_per_layout(rng, monkeypatch):
+    """The trimmed buckets' static row ranges ride the pytree aux: a jitted
+    trimmed GAT train step over loader batches of one layout traces once,
+    on single batches and on two shards stacked by ``stack_batches``."""
+    monkeypatch.setenv("REPRO_USE_PALLAS", "1")
+    n, e, feat = 300, 2400, 12
+    data = Data(x=rng.standard_normal((n, feat)).astype(np.float32),
+                edge_index=np.stack(_random_graph(rng, n, e)))
+    model = make_model("gat", feat, 8, 3, 3)
+    params = model.init(jax.random.PRNGKey(7))
+
+    def loss_fn(p, batch):
+        out = model.apply(p, batch.x, batch.edge_index,
+                          num_sampled_nodes_per_hop=batch.num_sampled_nodes,
+                          num_sampled_edges_per_hop=batch.num_sampled_edges,
+                          trim=True)
+        return (out[batch.seed_slots] ** 2).mean()
+
+    traces, stacked_traces = [], []
+
+    @jax.jit
+    def step(p, batch):
+        traces.append(1)
+        return jax.value_and_grad(loss_fn)(p, batch)
+
+    @jax.jit
+    def stacked_step(p, stacked):
+        stacked_traces.append(1)
+        shards = [jax.tree_util.tree_map(lambda l, i=i: l[i], stacked)
+                  for i in range(2)]
+        return jax.value_and_grad(
+            lambda p_: sum(loss_fn(p_, s) for s in shards))(p)
+
+    def loader(shards):
+        return iter(NeighborLoader(data, data, num_neighbors=[4, 3, 2],
+                                   batch_size=6 * shards, shards=shards,
+                                   prefill_ell=True, labels_attr=None,
+                                   seed=0))
+
+    single = loader(1)
+    b1, b2 = next(single), next(single)
+    losses = [float(step(params, b)[0]) for b in (b1, b2)]
+    assert len(traces) == 1, "second batch of one layout retraced"
+    stacked = loader(2)
+    s1, s2 = next(stacked), next(stacked)
+    stacked_losses = [float(stacked_step(params, s)[0]) for s in (s1, s2)]
+    assert len(stacked_traces) == 1, "second stacked batch retraced"
+    # the stacked loader's first batch holds the single loader's two
+    np.testing.assert_allclose(stacked_losses[0], sum(losses), rtol=1e-5)
 
 
 def test_trimmed_transpose_ell_serves_reversed_flow(rng, monkeypatch):

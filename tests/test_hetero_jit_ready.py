@@ -9,7 +9,8 @@ Covers the PR-3 chain:
           -> jit'd HeteroGNN step (ONE trace across batches)
              -> per-relation propagate -> spmm_ell_pallas
              -> all per-type projections -> ONE grouped matmul per layer
-      -> trim_to_layer_hetero keeps the masked ELL fast path on inner hops
+      -> trim_to_layer_hetero keeps the ELL fast path on inner hops, cut to
+         the rows each layer keeps
 """
 
 import jax
@@ -19,7 +20,7 @@ import pytest
 
 from repro.core.edge_index import EdgeIndex
 from repro.core.hetero import HeteroConv, to_hetero
-from repro.core.trim import trim_to_layer
+from repro.core.trim import trim_to_layer, trim_to_layer_hetero
 from repro.data.data import Data, HeteroData
 from repro.data.graph_store import DEFAULT_ETYPE
 from repro.data.hetero_sampler import (HeteroBatch, HeteroNeighborLoader,
@@ -298,6 +299,13 @@ def test_hetero_trim_preserves_seed_outputs(rng):
     # trimmed inner shapes actually shrink
     assert trim["item"].shape[0] < full["item"].shape[0] or \
         trim["user"].shape[0] < full["user"].shape[0]
+    # the static-layout ELL caches are cut to the rows layer 1 keeps
+    _, ei_t = trim_to_layer_hetero(1, b.num_sampled_nodes_dict,
+                                   b.num_sampled_edges_dict, b.x_dict,
+                                   b.edge_index_dict)
+    assert sum(r.shape[0] for ei in ei_t.values() for r, _, _ in ei._ell) \
+        < sum(r.shape[0] for ei in b.edge_index_dict.values()
+              for r, _, _ in ei._ell)
     # trim without the edge budgets is a hard error, not an obscure crash
     with pytest.raises(ValueError, match="num_sampled_edges_dict"):
         net.apply(params, b.x_dict, b.edge_index_dict,
@@ -318,9 +326,18 @@ def test_trim_keeps_ell_fast_path(rng, monkeypatch):
     x_t, ei_t, _ = trim_to_layer(1, b.num_sampled_nodes,
                                  b.num_sampled_edges, b.x, b.edge_index)
     assert ei_t._ell is not None
-    # identical shapes to the parent's cache (jit-stable across layers)
-    assert [tuple(a.shape for a in bk) for bk in ei_t._ell] == \
-           [tuple(a.shape for a in bk) for bk in b.edge_index._ell]
+    # the static-layout buckets are cut to the rows layer 1 keeps: shorter
+    # than the parent's, still row-block multiples, kept rows unchanged
+    for (r_t, i_t, p_t), (r, i, p) in zip(ei_t._ell, b.edge_index._ell):
+        n = r_t.shape[0]
+        assert n % 8 == 0 and n < r.shape[0]
+        kept = np.asarray(r_t) >= 0
+        np.testing.assert_array_equal(np.asarray(r_t)[kept],
+                                      np.asarray(r)[:n][kept])
+        np.testing.assert_array_equal(np.asarray(i_t)[kept],
+                                      np.asarray(i)[:n][kept])
+        np.testing.assert_array_equal(np.asarray(p_t)[kept],
+                                      np.asarray(p)[:n][kept])
     raw = EdgeIndex(ei_t.data, x_t.shape[0], x_t.shape[0])
     for reduce in ("sum", "mean", "max", "min"):
         fast = ei_t.matmul(x_t, reduce=reduce, force_pallas=True)

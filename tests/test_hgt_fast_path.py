@@ -25,6 +25,7 @@ import pytest
 
 from repro.core.edge_index import EdgeIndex
 from repro.core.hetero import HGTConv, hgt
+from repro.core.trim import trim_to_layer_hetero
 from repro.data.data import HeteroData
 from repro.data.hetero_sampler import HeteroNeighborLoader
 from repro.kernels.attention import ops as attn_ops
@@ -357,6 +358,13 @@ def test_hgt_trim_preserves_seed_outputs(rng, monkeypatch):
     np.testing.assert_allclose(np.asarray(b.seed_output(full)),
                                np.asarray(b.seed_output(trim)), rtol=1e-3,
                                atol=1e-4)
+    # the static-layout ELL caches are cut to the rows layer 1 keeps
+    _, ei_t = trim_to_layer_hetero(1, b.num_sampled_nodes_dict,
+                                   b.num_sampled_edges_dict, b.x_dict,
+                                   b.edge_index_dict)
+    assert sum(r.shape[0] for ei in ei_t.values() for r, _, _ in ei._ell) \
+        < sum(r.shape[0] for ei in b.edge_index_dict.values()
+              for r, _, _ in ei._ell)
 
 
 # ------------------------------------------------------ GAT bit-identity
