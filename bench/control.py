@@ -88,7 +88,7 @@ def readings_for_seed(cell, seed: int):
         out[kind] = correct_lib.compare(got, ref)
         out[kind]["worst"] = worst_leaves(got, ref, session.params0)
     out["batch_mismatches"] = sum(
-        run.batch_mismatches(session.graph, s)
+        session.kind.batch_mismatches(session.graph, s)
         for step in session.shards for s in step)
     return out
 

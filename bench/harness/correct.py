@@ -14,6 +14,7 @@ cell's limits (``bench/limits/<workload>.json``).
 
 from __future__ import annotations
 
+import json
 import math
 from typing import Callable, Dict, List, Optional
 
@@ -21,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness.reference import reference_inputs
+from harness import graph_kind
 
 CHECK_STEPS = 3
 # A leaf whose reference gradient is under this share of the median
@@ -56,7 +57,8 @@ _GRAD_FNS: Dict = {}
 
 
 def _grad_fn(model_mod, cfg, hops, numerics):
-    key = (model_mod.__name__, hops, numerics)
+    key = (model_mod.__name__, json.dumps(cfg, sort_keys=True), hops,
+           numerics)
     if key not in _GRAD_FNS:
         def loss(params, inp):
             inp = dict(inp, nodes_per_hop=hops[0], edges_per_hop=hops[1])
@@ -75,6 +77,7 @@ def reference_run(model_mod, cfg, graph, batches: List[List[Dict]], params0,
     control and the witness); ``seed_weight`` rewrites each shard's seed
     weights and ``shards`` keeps only those shards (planted faults)."""
     opt = cfg["optimizer"]
+    reference_inputs = graph_kind.of(cfg).reference_inputs
     p = jax.tree_util.tree_map(jnp.asarray, params0)
     mu = jax.tree_util.tree_map(jnp.zeros_like, p)
     nu = jax.tree_util.tree_map(jnp.zeros_like, p)

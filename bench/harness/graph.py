@@ -1,8 +1,9 @@
 """The host graph a cell trains on, and the check of a batch against it.
 
 A generator (``bench/graphs/<name>.py``, named by the configuration's
-``graph_generator``) returns a :class:`HostGraph` straight in the layout
-the program's stores keep: incoming adjacency as CSR over destination
+``graph_generator``) returns a :class:`HostGraph` (a heterogeneous one
+``harness.hetero.HeteroHostGraph``) straight in the layout the program's
+stores keep: incoming adjacency as CSR over destination
 rows, edge ids numbered in that order. :func:`program_store` hands it to
 the program's ``Data`` with the reverse-CSR cache filled, so the sampler
 never sorts 62M edges at start-up.

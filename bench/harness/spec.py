@@ -12,7 +12,34 @@ per-layer metric lives in a file of its own, found by name:
   * ``bench/metrics/<metric>.py``              one per-layer metric reader.
 
 Adding a configuration, a mix, a cell or a metric adds files and entries
-only; nothing here names any of them.
+only; nothing here names any of them. A configuration with a
+``node_types`` key is heterogeneous (``harness.hetero``); any other is
+one node type and one edge type (``harness.graph``). The harness picks
+the kind once, from the configuration (``harness.graph_kind``).
+
+A model module (``bench/models/<name>.py``) provides:
+
+  * ``program_model(cfg)``   the program's model, whose ``apply`` the
+                             timed step calls;
+  * ``init_params(key, cfg)``  weights from the seed, in the program
+                             model's tree layout;
+  * ``reference_loss(params, inp, cfg, numerics)``  (loss sum, seed
+                             weight) of one shard in plain JAX, with
+                             ``inp`` from the kind's ``reference_inputs``;
+  * ``aggregations(cfg, counts)``  per layer (and, heterogeneous, per
+                             relation) the real receiving rows, real
+                             edges, width and heads of each aggregation;
+  * ``step_flops(cfg, counts)``  forward and backward FLOPs of one
+                             shard's step,
+
+where ``counts`` is the kind's ``real_counts`` of a shard. Homogeneous,
+``inp`` holds ``x``, ``src``, ``dst`` and ``valid`` as arrays and
+``counts`` per-hop lists ``nodes`` and ``edges``; heterogeneous, each of
+those is a dict by node type (``x``, ``nodes``) or by relation
+(``src``, ``dst``, ``valid``, ``edges``; relations are
+``(src, rel, dst)`` tuples), and ``nodes_per_hop``/``edges_per_hop``
+are sorted ``(type or relation, per-hop counts)`` pairs. Both hold
+``seed_slots``, ``y`` and ``w`` (1 for a real seed, 0 for padding).
 """
 
 from __future__ import annotations
@@ -63,7 +90,10 @@ def _applies(metric: Dict[str, Any], workload: str, e2e_names: set) -> bool:
 
 
 def load_cell(workload: str, root: str = ROOT) -> Cell:
+    """The cell ``workload`` of ``<root>/BENCHMARK.json``, with its mix and
+    limits from ``<root>/bench/``."""
     bench = load_benchmark(root)
+    bench_dir = os.path.join(root, os.path.basename(BENCH_DIR))
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
         raise SpecError(f"no workload {workload!r} in BENCHMARK.json; "
@@ -72,13 +102,13 @@ def load_cell(workload: str, root: str = ROOT) -> Cell:
     configs = {c["name"]: c for c in bench["configs"]}
     cfg_entry = configs[w["config"]]
     config = _read_json(os.path.join(root, cfg_entry["file"]))
-    traffic = _read_json(os.path.join(BENCH_DIR, "traffic",
+    traffic = _read_json(os.path.join(bench_dir, "traffic",
                                       f"{w['traffic']}.json"))
     if int(traffic.get("data_parallel", 1)) != int(w["chips"]):
         raise SpecError(f"{workload}: traffic {w['traffic']!r} is data-"
                         f"parallel over {traffic.get('data_parallel', 1)} "
                         f"devices but the cell asks for {w['chips']} chips")
-    limits = _read_json(os.path.join(BENCH_DIR, "limits", f"{workload}.json"))
+    limits = _read_json(os.path.join(bench_dir, "limits", f"{workload}.json"))
     e2e = [m for m in bench["end_to_end"]
            if "workloads" not in m or workload in m["workloads"]]
     e2e_names = {m["name"] for m in e2e}

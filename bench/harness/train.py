@@ -1,8 +1,9 @@
 """The system under test, driven as a user trains with it.
 
-One :class:`Trainer` owns the program's ``NeighborLoader`` over the graph,
-the program's model, its Adam (``repro.train.optimizer``) and either a
-jitted single-device step or ``repro.launch.train.MeshTrainer`` over a
+One :class:`Trainer` owns the program's loader over the graph (the graph
+kind's: ``NeighborLoader`` or ``HeteroNeighborLoader``), the program's
+model, its Adam (``repro.train.optimizer``) and either a jitted
+single-device step or ``repro.launch.train.MeshTrainer`` over a
 data-parallel mesh. Every optimizer step, in set-up and in the window
 alike, goes through :meth:`Trainer.step`: the next loader batch, the jitted
 loss + gradients + update, then ``block_until_ready``.
@@ -78,25 +79,42 @@ def program_loss(model, trim: bool):
     return loss_fn
 
 
+def neighbor_loader(store, graph, cell, seed: int):
+    """The program's ``NeighborLoader`` over ``store`` as the cell's mix
+    says, one shard per chip."""
+    from repro.data.loader import NeighborLoader
+
+    tr = cell.traffic
+    return NeighborLoader(
+        store, store, num_neighbors=list(tr["num_neighbors"]),
+        batch_size=int(tr["batch_per_chip"]) * cell.chips,
+        input_nodes=graph.train_nodes,
+        shuffle=bool(tr["shuffle"]), drop_last=bool(tr["drop_last"]),
+        pipeline_depth=int(tr["pipeline_depth"]),
+        prefetch=int(tr["prefetch"]), shards=cell.chips, seed=seed)
+
+
 class Trainer:
     """Loader + model + optimizer + step of one cell, on ``chips`` devices.
 
+    ``kind`` (``harness.graph_kind``) makes the loader and the loss.
     ``step_hook`` wraps the built step (the fault tests break it there).
     """
 
-    def __init__(self, cell, model_mod, store, train_nodes: np.ndarray,
-                 params, seed: int, *,
+    def __init__(self, cell, kind, model_mod, store, graph, params,
+                 seed: int, *,
                  step_hook: Optional[Callable] = None,
                  loss_hook: Optional[Callable] = None):
-        from repro.data.loader import NeighborLoader
         from repro.train import optimizer as opt_lib
 
         cfg, tr = cell.config, cell.traffic
         self.chips = cell.chips
         self.seeds_per_step = int(tr["batch_per_chip"]) * self.chips
+        # first: a kind that cannot serve the cell refuses it here
+        self.loader = kind.make_loader(store, graph, cell, seed)
         self.model = model_mod.program_model(cfg)
         self.opt_cfg = opt_config(cfg)
-        loss_fn = program_loss(self.model, bool(cfg["trim"]))
+        loss_fn = kind.loss(self.model, bool(cfg["trim"]))
         if loss_hook is not None:
             loss_fn = loss_hook(loss_fn)
         state = opt_lib.init_state(params, self.opt_cfg)
@@ -121,12 +139,6 @@ class Trainer:
             self._step = mesh_step
         if step_hook is not None:
             self._step = step_hook(self._step)
-        self.loader = NeighborLoader(
-            store, store, num_neighbors=list(tr["num_neighbors"]),
-            batch_size=self.seeds_per_step, input_nodes=train_nodes,
-            shuffle=bool(tr["shuffle"]), drop_last=bool(tr["drop_last"]),
-            pipeline_depth=int(tr["pipeline_depth"]),
-            prefetch=int(tr["prefetch"]), shards=self.chips, seed=seed)
         self._batches = self._epochs()
 
     def _single_step(self, loss_fn):

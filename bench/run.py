@@ -45,12 +45,8 @@ sys.path.insert(0, BENCH)
 sys.path.insert(1, os.path.join(ROOT, "src"))
 
 from harness import correct as correct_lib  # noqa: E402
-from harness import spec  # noqa: E402
-from harness.graph import (batch_mismatches, generate,  # noqa: E402
-                           program_store)
-from harness.reference import real_counts  # noqa: E402
-from harness.train import (SPANS, WINDOW_SPAN, Spans, Trainer,  # noqa: E402
-                           host_shards)
+from harness import graph_kind, spec  # noqa: E402
+from harness.train import SPANS, WINDOW_SPAN, Spans, Trainer  # noqa: E402
 
 TRACE_DIR = os.path.join(BENCH, ".out", "trace")
 
@@ -101,6 +97,7 @@ class Session:
     """One cell's program, set up from a seed, with what its first steps
     left for the comparison."""
     cell: Any
+    kind: graph_kind.GraphKind
     model_mod: Any
     graph: Any
     trainer: Trainer
@@ -117,10 +114,11 @@ def setup(cell, seed: int, spans: Spans, *,
     """Graph, weights and the program's first ``CHECK_STEPS`` steps."""
     import jax
 
+    kind = graph_kind.of(cell.config)
     model_mod = spec.load_module("models", cell.config["model"])
-    graph = generate(cell.config, seed)
+    graph = kind.generate(cell.config, seed)
     note(f"graph: {graph.num_nodes} nodes, {graph.num_edges} edges")
-    store = program_store(graph)
+    store = kind.program_store(graph)
     note("program store")
     init = jax.jit(lambda k: model_mod.init_params(k, cell.config))
     params = init(jax.random.PRNGKey(seed))
@@ -135,7 +133,7 @@ def setup(cell, seed: int, spans: Spans, *,
                              f"weights that do not fit the program's model")
     params0 = jax.device_get(params)
     note("weights")
-    trainer = Trainer(cell, model_mod, store, graph.train_nodes, params, seed,
+    trainer = Trainer(cell, kind, model_mod, store, graph, params, seed,
                       step_hook=step_hook, loss_hook=loss_hook)
     del params
     losses, shards, mu1 = [], [], None
@@ -145,15 +143,15 @@ def setup(cell, seed: int, spans: Spans, *,
             losses.append(float(loss))
             if k == 0:
                 mu1 = jax.device_get(trainer.state.mu)
-            shards.append(host_shards(batch, cell.chips))
+            shards.append(kind.host_shards(batch, cell.chips))
             del batch
             note(f"check step {k + 1}: loss {losses[-1]!r}")
         params3 = jax.device_get(trainer.state.params)
     except BaseException:
         trainer.close()
         raise
-    return Session(cell, model_mod, graph, trainer, params0, losses, mu1,
-                   params3, shards)
+    return Session(cell, kind, model_mod, graph, trainer, params0, losses,
+                   mu1, params3, shards)
 
 
 def settle(session: Session, spans: Spans) -> None:
@@ -214,7 +212,7 @@ def verify(session: Session, window: Dict[str, Any]):
         session.cell.config)
     readings = correct_lib.compare(prog, ref)
     readings["batch_mismatches"] = sum(
-        batch_mismatches(session.graph, s)
+        session.kind.batch_mismatches(session.graph, s)
         for step in session.shards for s in step)
     readings["window_compiles"] = window["compiles"]
     readings["nonfinite_losses"] = sum(
@@ -258,7 +256,8 @@ def per_layer_record(session: Session, window: Dict[str, Any],
                      device_kind: str) -> Dict[str, Any]:
     """What the per-layer metric readers read."""
     cfg = session.cell.config
-    counts = [real_counts(s) for step in session.shards for s in step]
+    counts = [session.kind.real_counts(s)
+              for step in session.shards for s in step]
     per_shard = [session.model_mod.aggregations(cfg, c) for c in counts]
     aggs = []
     for layer in range(len(per_shard[0])):

@@ -4,7 +4,8 @@ Each test drives a whole run of a toy-sized cell on the CPU (the look for
 a chip skipped), with the timed path broken underneath, and sees
 ``correct`` come out false; the unbroken run comes out true. The control,
 the reference computed in bfloat16 in the program's place, must fail each
-cell's limits too.
+cell's limits too. The cells are the benchmark's GAT cell and the
+heterogeneous fixture ``rsage-toy.train`` (``data/hetero/``).
 """
 
 from __future__ import annotations
@@ -19,10 +20,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from conftest import BENCH, toy_cell
+from conftest import BENCH, HETERO_ROOT, toy_cell
 
 SEED = 2**31 + 99
-CELLS = ["gat-products.train"]
+CELLS = ["gat-products.train", "rsage-toy.train"]
 
 
 def _run(cell, **hooks):
@@ -34,6 +35,26 @@ def _run(cell, **hooks):
 def _failed(result):
     return sorted(k for k, c in result["checks"].items()
                   if not c["value"] <= c["limit"])
+
+
+def _without_seeds(batch, slots):
+    """``batch`` with the seeds at ``slots`` marked as padding."""
+    if hasattr(batch, "n_id_dict"):
+        n_id = dict(batch.n_id_dict)
+        n_id[batch.seed_type] = n_id[batch.seed_type].at[slots].set(-1)
+        return dataclasses.replace(batch, n_id_dict=n_id)
+    return dataclasses.replace(batch, n_id=batch.n_id.at[slots].set(-1))
+
+
+def _altered_row(batch):
+    """``batch`` with one feature row changed: a seed's, or on a typed
+    graph the first author's."""
+    if hasattr(batch, "x_dict"):
+        x = dict(batch.x_dict)
+        x["author"] = x["author"].at[1].add(1.0)
+        return dataclasses.replace(batch, x_dict=x)
+    row = batch.seed_slots[0]
+    return dataclasses.replace(batch, x=batch.x.at[row].add(1.0))
 
 
 @pytest.mark.parametrize("workload", CELLS)
@@ -63,8 +84,7 @@ def test_half_batch_fails(workload):
     def hook(loss_fn):
         def half(params, batch):
             drop = batch.seed_slots[batch.seed_slots.shape[0] // 2:]
-            return loss_fn(params, dataclasses.replace(
-                batch, n_id=batch.n_id.at[drop].set(-1)))
+            return loss_fn(params, _without_seeds(batch, drop))
         return half
 
     result = _run(toy_cell(workload), loss_hook=hook)
@@ -73,15 +93,14 @@ def test_half_batch_fails(workload):
 
 @pytest.mark.parametrize("workload", CELLS)
 def test_altered_batch_fails(workload, monkeypatch):
-    """One seed's feature row altered where the loader produces it."""
+    """One feature row altered where the loader produces it."""
     from harness import train
 
     produce = train.Trainer._epochs
 
     def altered(self):
         for b in produce(self):
-            row = b.seed_slots[0]
-            yield dataclasses.replace(b, x=b.x.at[row].add(1.0))
+            yield _altered_row(b)
 
     monkeypatch.setattr(train.Trainer, "_epochs", altered)
     result = _run(toy_cell(workload))
@@ -138,3 +157,50 @@ def test_exchange_left_out_fails():
     out = json.loads(proc.stdout.strip().splitlines()[-1])
     assert out["sound"][0], out["sound"][1]
     assert not out["no_psum"][0], out["no_psum"][1]
+
+
+def test_misdirected_edge_fails(monkeypatch):
+    """One ``writes`` edge pointed at another paper where the sampler makes
+    it, so the loader packs it into every layout of the batch."""
+    from repro.data.hetero_sampler import HeteroNeighborSampler
+
+    sample = HeteroNeighborSampler.sample
+    writes = ("author", "writes", "paper")
+
+    def misdirected(self, *args, **kwargs):
+        out = sample(self, *args, **kwargs)
+        col = out.col[writes]
+        col[0] = 2 if col[0] == 1 else 1
+        return out
+
+    monkeypatch.setattr(HeteroNeighborSampler, "sample", misdirected)
+    result = _run(toy_cell("rsage-toy.train"))
+    assert not result["correct"]
+    assert "batch_mismatches" in _failed(result)
+
+
+def test_hetero_cell_on_four_chips_is_refused():
+    """The program's heterogeneous loader has no shards."""
+    from harness import spec
+
+    cell = dataclasses.replace(toy_cell("rsage-toy.train"), chips=4)
+    with pytest.raises(spec.SpecError, match="one chip"):
+        _run(cell)
+
+
+def test_config_added_from_files_alone():
+    """A second heterogeneous configuration (three types in a cycle, no
+    reverses, a fanout list per relation, three layers) with its mix and
+    limits, found by name: nothing in the harness names it."""
+    import glob
+
+    from harness import spec
+
+    cell = spec.load_cell("rsage-cycle.train", root=HETERO_ROOT)
+    for path in glob.glob(os.path.join(BENCH, "harness", "*.py")) + [
+            os.path.join(BENCH, "run.py")]:
+        with open(path) as f:
+            assert "rsage" not in f.read(), path
+    result = _run(cell)
+    assert result["correct"], result["checks"]
+    assert cell.config["model"] == "rsage"

@@ -150,6 +150,118 @@ def test_graph_matches_program_store():
     assert len(np.unique(g.train_nodes)) == 50
 
 
+def _pairs(adj):
+    """A relation's edges as sorted (src, dst) rows."""
+    dst = np.repeat(np.arange(len(adj.indptr) - 1), np.diff(adj.indptr))
+    pairs = np.stack([adj.indices, dst], 1)
+    return pairs[np.lexsort((pairs[:, 1], pairs[:, 0]))]
+
+
+def test_hetero_graph_matches_program_store():
+    """Every relation's reverse CSR, reverses and the symmetric relation
+    included, is what the program's store derives from the same edges;
+    reverses hold the flipped edges; the seed alone fixes the graph."""
+    from harness.graph import generate
+    from harness.hetero import program_store, relations
+    from repro.data.data import HeteroData
+
+    cfg = {"graph_generator": "uniform_hetero",
+           "node_types": {"paper": {"num_nodes": 300, "num_features": 3},
+                          "author": {"num_nodes": 70000,
+                                     "num_features": 2}},
+           "edge_types": [["author", "writes", "paper", 5000],
+                          ["paper", "cites", "paper", 2000]],
+           "reverse_edges": True, "target_type": "paper",
+           "num_classes": 4, "num_train_nodes": 30}
+    g = generate(cfg, 2**33 + 5)
+    assert list(g.adj) == relations(cfg) == [
+        ("author", "writes", "paper"), ("paper", "rev_writes", "author"),
+        ("paper", "cites", "paper")]
+    store = program_store(g)
+    plain = HeteroData()
+    for t, x in g.x.items():
+        plain.add_nodes(t, x)
+    for rel, a in g.adj.items():
+        dst = np.repeat(np.arange(len(a.indptr) - 1), np.diff(a.indptr))
+        plain.add_edges(rel, np.stack([a.indices, dst]))
+        got, want = store.get_rev_csr(rel), plain.get_rev_csr(rel)
+        for x, y in ((got.indptr, want.indptr), (got.indices, want.indices),
+                     (got.edge_id, want.edge_id)):
+            np.testing.assert_array_equal(x, y)
+    writes = _pairs(g.adj[("author", "writes", "paper")])
+    rev = _pairs(g.adj[("paper", "rev_writes", "author")])[:, ::-1]
+    np.testing.assert_array_equal(
+        rev[np.lexsort((rev[:, 1], rev[:, 0]))], writes)
+    cites = _pairs(g.adj[("paper", "cites", "paper")])
+    assert len(cites) == 4000
+    flipped = cites[:, ::-1]
+    np.testing.assert_array_equal(
+        flipped[np.lexsort((flipped[:, 1], flipped[:, 0]))], cites)
+    again = generate(cfg, 2**33 + 5)
+    for rel in g.adj:
+        np.testing.assert_array_equal(again.adj[rel].indices,
+                                      g.adj[rel].indices)
+    np.testing.assert_array_equal(again.x["author"], g.x["author"])
+    other = generate(cfg, 1)
+    assert not np.array_equal(other.adj[("paper", "cites", "paper")].indices,
+                              cites)
+    assert len(np.unique(g.train_nodes)) == 30 and g.y.shape == (300,)
+
+
+def test_rsage_on_one_type_counts_as_sage():
+    """On one node type and one relation the heterogeneous SAGE's work is
+    the homogeneous SAGE's."""
+    from harness import spec
+
+    sage = spec.load_module("models", "sage")
+    rsage = spec.load_module("models", "rsage")
+    counts = {"nodes": [1, 4, 6], "edges": [4, 6]}
+    cfg = {"num_features": 2, "hidden": 3, "num_classes": 5, "num_layers": 2}
+    rel = ("n", "to", "n")
+    hcfg = dict(cfg, node_types={"n": {"num_nodes": 9, "num_features": 2}},
+                edge_types=[["n", "to", "n", 9]], reverse_edges=False)
+    hcounts = {"nodes": {"n": counts["nodes"]},
+               "edges": {rel: counts["edges"]}}
+    assert rsage.step_flops(hcfg, hcounts) == sage.step_flops(cfg, counts)
+    got = rsage.aggregations(hcfg, hcounts)
+    assert [dict(a, relation=None) for a in got] == [
+        dict(a, relation=None) for a in sage.aggregations(cfg, counts)]
+    assert {a["relation"] for a in got} == {"n__to__n"}
+
+
+def test_hetero_record_counts_each_relation():
+    """The per-layer record of a heterogeneous cell carries one aggregation
+    per layer and relation, from the batch's real rows and edges."""
+    import run
+    from conftest import toy_cell
+    from harness import spec
+    from harness.hetero import rel_key, relations
+    from harness.train import Spans
+
+    cell = toy_cell("rsage-toy.train")
+    session = run.setup(cell, 2**31 + 7, Spans())
+    session.trainer.close()
+    rec = run.per_layer_record(
+        session, {"steps": 4, "window_s": 2.0, "spans_s": {}}, None, None,
+        0, None, "TPU v5 lite")
+    rels = [rel_key(r) for r in relations(cell.config)]
+    aggs = rec["aggregations"]
+    assert [(a["layer"], a["relation"]) for a in aggs] == [
+        (layer, r) for layer in range(2) for r in rels]
+    shard = session.shards[0][0]
+    for a in aggs:
+        rel = next(r for r in relations(cell.config) if rel_key(r) == a[
+            "relation"])
+        budget = sum(shard["edges_per_hop"][rel][:2 - a["layer"]])
+        assert 0 <= a["edges"] <= budget
+        if rel[2] == "paper":
+            assert a["edges"] > 0
+        elif a["layer"] == 1:  # the last layer reads edges into seeds only
+            assert a["edges"] == 0
+    assert rec["step_flops"] > 0
+    assert 0 < spec.load_module("metrics", "step_mfu").read(rec) < 100
+
+
 # ---------------------------------------------------------------- spec
 def test_every_cell_resolves_by_name():
     from harness import spec
