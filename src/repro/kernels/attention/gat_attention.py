@@ -10,9 +10,9 @@ layout the SpMM kernel consumes:
 in a single VMEM pass per row block (flash style): the softmax runs
 *online* — a running max / running sum rescale the feature accumulator as
 neighbor columns stream in — so the ``(E, H, F)`` edge-message tensor of the
-materialised path is never built. Per neighbor column the kernel issues two
-batches of async HBM->VMEM copies (the ``(1, F)`` feature row and the
-``(1, H*LD)`` sender-term row of each neighbor), double-buffered exactly
+materialised path is never built. Per (row, neighbor) slot the kernel issues
+exactly two async HBM->VMEM copies: the neighbor's whole ``(1, H*F)``
+feature row and its ``(1, H*LD)`` sender-term row, double-buffered exactly
 like the SpMM kernel's pipelined gather, with the scalar-prefetched neighbor
 table as the DMA address stream.
 
@@ -30,16 +30,18 @@ launches targeting the same destination rows can be merged ops-side into
 one cross-relation softmax (see ``kernels/attention/__init__.py`` for the
 merge convention).
 
-Layout: ``z`` arrives flattened to ``(N, H*F)`` so the head axis rides the
-feature grid dimension (the per-head feature slice starts at ``h * F``) and
-the DMA indexing stays 2-D. The receiver term is pre-gathered per bucket
-row host/XLA-side (it is keyed by *row ids*, not by the neighbor table) and
-enters as a dense ``(R, H*LD)`` VMEM panel.
+Layout: ``z`` arrives as ``(N, 1, H*F_pad)``, each head's features
+zero-padded to whole 128-lane tiles (``_head_table``), so head h's slice of
+a gathered row is the static, lane-aligned ``[h*F_pad, (h+1)*F_pad)`` and
+the DMA indexing stays row-only. The receiver term is pre-gathered per
+bucket row host/XLA-side (it is keyed by *row ids*, not by the neighbor
+table) and enters as a dense ``(R, H*LD)`` VMEM panel.
 
-Grid: ``(num_row_blocks, heads, num_feat_blocks)``; each (row, head, feat)
-tile recomputes the cheap ``(BR, K)`` online softmax and is written once
-(the tiny ``(BR, 1)`` carry blocks are revisited across feat tiles with
-identical values).
+Grid: ``(num_row_blocks,)``; each row-block tile owns all heads. The heads
+are a static loop inside the tile, each with its own ``(BR, 1)`` online
+softmax state and ``(BR, F_pad)`` accumulator, so one gathered row serves
+every head. The out block is the whole ``(BR, H*F_pad)`` row panel; on carry
+launches the ``(m, l)`` blocks are ``(H, BR, 1)``.
 
 ``_gat_ell_kernel`` is a named delegator to the same body: Pallas reports
 the kernel *function name* in the jaxpr, and the dispatch auditor / cost
@@ -57,17 +59,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.budgets import DEFAULT_BF, DEFAULT_BR, lane_tiles
+from repro.kernels.budgets import DEFAULT_BR, lane_tiles
 from repro.kernels.spmm.spmm import gather_table, lane_pick, pad_lanes
 _NUM_SLOTS = 2  # double buffering
 
 
 def _attn_ell_kernel(idx_sref, idx_ref, adst_ref, w_ref, *rest,
-                     block_rows: int, block_feat: int, k: int, heads: int,
-                     feat: int, negative_slope: float, has_weight: bool,
+                     block_rows: int, k: int, heads: int, feat: int,
+                     negative_slope: float, has_weight: bool,
                      logit_kind: str = "add", logit_dim: int = 1,
                      return_carry: bool = False):
-    """One (row_block, head, feat_block) tile: online-softmax accumulate.
+    """One row-block tile, all heads: online-softmax accumulate.
 
     ``idx_sref``   full neighbor table flattened to (R*K,), scalar-prefetched
                    (SMEM) — the DMA address stream (flat, as in the SpMM
@@ -79,30 +81,27 @@ def _attn_ell_kernel(idx_sref, idx_ref, adst_ref, w_ref, *rest,
     ``rest``       [prior_ref] asrc_hbm z_hbm out_ref [m_ref l_ref]
                    zgather agather sems — the prior operand and the carry
                    outputs exist only on ``return_carry`` launches.
-    ``asrc_hbm``   (N, 1, H*LD padded) sender term, ``z_hbm`` (N, 1, H*F
-                   padded) features: HBM tables with a unit axis, so that
+    ``asrc_hbm``   (N, 1, H*LD padded) sender term, ``z_hbm`` (N, 1,
+                   H*feat) features: HBM tables with a unit axis, so that
                    each single-row DMA is tile-aligned (see the SpMM
                    kernel).
-    ``zgather``    (2, BR, 1, BF) VMEM scratch — feature-row landing zone.
+    ``zgather``    (2, BR, 1, H*feat) VMEM scratch — feature-row landing
+                   zone.
     ``agather``    (2, BR, 1, H*LD padded) VMEM scratch — sender rows.
     ``sems``       (2, 2, BR) DMA semaphores: [0] features, [1] alphas.
+    ``feat``       per-head feature width, a whole number of lane tiles.
     """
     if return_carry:
         (prior_ref, asrc_hbm, z_hbm, out_ref, m_ref, l_ref, zgather,
          agather, sems) = rest
     else:
         asrc_hbm, z_hbm, out_ref, zgather, agather, sems = rest
-    r_blk = pl.program_id(0)
-    h = pl.program_id(1)
-    f_blk = pl.program_id(2)
-    row_base = r_blk * block_rows
-    # z is (N, H*F): head h's feature block starts at h*F + f_blk*BF.
-    f_start = h * feat + f_blk * block_feat
+    row_base = pl.program_id(0) * block_rows
 
     def z_dma(slot, kk, r):
         nid = jnp.maximum(idx_sref[(row_base + r) * k + kk], 0)
         return pltpu.make_async_copy(
-            z_hbm.at[pl.ds(nid, 1), :, pl.ds(f_start, block_feat)],
+            z_hbm.at[pl.ds(nid, 1)],
             zgather.at[slot, pl.ds(r, 1)],
             sems.at[0, slot, r],
         )
@@ -129,22 +128,23 @@ def _attn_ell_kernel(idx_sref, idx_ref, adst_ref, w_ref, *rest,
             return carry
         jax.lax.fori_loop(0, block_rows, body_r, 0)
 
-    # Columns of the (BR, K) panels and this head's lanes of the (BR, H*LD)
+    # Columns of the (BR, K) panels and each head's lanes of the (BR, H*LD)
     # ones are picked with iota masks: lane-dynamic slices do not lower on
     # the TPU.
     idx_panel = idx_ref[...]  # (BR, K)
     valid_panel = (idx_panel >= 0).astype(jnp.float32)
     lane_k = jax.lax.broadcasted_iota(jnp.int32, (block_rows, k), 1)
     lane_h = jax.lax.broadcasted_iota(jnp.int32, adst_ref.shape, 1)
-    head_lanes = (lane_h >= h * logit_dim) & (lane_h < (h + 1) * logit_dim)
+    head_lanes = [(lane_h >= h * logit_dim) & (lane_h < (h + 1) * logit_dim)
+                  for h in range(heads)]
     adst = adst_ref[...].astype(jnp.float32)
     if logit_kind == "add":
-        adst_col = lane_pick(head_lanes, adst)  # (BR, 1): this head
-    else:  # dot: this head's query lanes (others zeroed) + scalar prior
-        q_head = jnp.where(head_lanes, adst, 0.0)
-        prior_col = lane_pick(
-            jax.lax.broadcasted_iota(jnp.int32, (1, heads), 1) == h,
-            prior_ref[...].astype(jnp.float32))  # (1, 1)
+        adst_cols = [lane_pick(hl, adst) for hl in head_lanes]  # (BR, 1)
+    else:  # dot: each head's query lanes (others zeroed) + scalar prior
+        q_heads = [jnp.where(hl, adst, 0.0) for hl in head_lanes]
+        prior = prior_ref[...].astype(jnp.float32)             # (1, H)
+        lane_p = jax.lax.broadcasted_iota(jnp.int32, (1, heads), 1)
+        prior_cols = [lane_pick(lane_p == h, prior) for h in range(heads)]
     if has_weight:
         w_panel = w_ref[...].astype(jnp.float32)
 
@@ -153,10 +153,9 @@ def _attn_ell_kernel(idx_sref, idx_ref, adst_ref, w_ref, *rest,
 
     m0 = jnp.full((block_rows, 1), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((block_rows, 1), jnp.float32)
-    acc0 = jnp.zeros((block_rows, block_feat), jnp.float32)
+    acc0 = jnp.zeros((block_rows, feat), jnp.float32)
 
     def body_k(kk, carry):
-        m, l, acc = carry
         slot = jax.lax.rem(kk, _NUM_SLOTS)
 
         # Prefetch column kk+1 into the other slot while kk lands/computes.
@@ -165,40 +164,51 @@ def _attn_ell_kernel(idx_sref, idx_ref, adst_ref, w_ref, *rest,
             start_column(1 - slot, kk + 1)
 
         wait_column(slot, kk)
-        ztile = zgather[slot].reshape(block_rows, block_feat).astype(
-            jnp.float32)                             # (BR, BF)
         arows = agather[slot].reshape(adst_ref.shape).astype(
             jnp.float32)                             # (BR, H*LD padded)
-
         col = lane_k == kk
         valid = lane_pick(col, valid_panel) > 0.0    # (BR, 1)
-        if logit_kind == "add":
-            logit = lane_pick(head_lanes, arows) + adst_col
-            logit = jnp.where(logit >= 0, logit, negative_slope * logit)
-        else:  # dot: <k[nbr], q[row]> over this head's LD lanes, scaled
-            logit = jnp.sum(arows * q_head, axis=1, keepdims=True) * prior_col
-        logit = jnp.where(valid, logit, -jnp.inf)
+        if has_weight:
+            w_col = lane_pick(col, w_panel)
 
-        # Online softmax: rescale the accumulator by exp(m - m_new). While a
-        # row has seen no valid neighbor m is -inf and every term is 0.
-        m_new = jnp.maximum(m, logit)
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.where(valid, jnp.exp(logit - m_safe), 0.0)    # (BR, 1)
-        corr = jnp.exp(m - m_safe)  # exp(-inf) = 0 zeroes the empty prefix
-        num = p if not has_weight else p * lane_pick(col, w_panel)
-        return m_new, l * corr + p, acc * corr + num * ztile
+        heads_out = []
+        for h, (m, l, acc) in enumerate(zip(*carry)):
+            if logit_kind == "add":
+                logit = lane_pick(head_lanes[h], arows) + adst_cols[h]
+                logit = jnp.where(logit >= 0, logit, negative_slope * logit)
+            else:  # dot: <k[nbr], q[row]> over head h's LD lanes, scaled
+                logit = jnp.sum(arows * q_heads[h], axis=1,
+                                keepdims=True) * prior_cols[h]
+            logit = jnp.where(valid, logit, -jnp.inf)
 
-    m, l, acc = jax.lax.fori_loop(0, k, body_k, (m0, l0, acc0))
-    if return_carry:
-        # Unnormalised carry out: the ops layer merges (m, l, acc) triples
-        # across relation launches before the single finalize divide. The
-        # (BR, 1) carry blocks are revisited per feat tile — same values.
-        out_ref[...] = acc.astype(out_ref.dtype)
-        m_ref[...] = m.astype(m_ref.dtype)
-        l_ref[...] = l.astype(l_ref.dtype)
-    else:
-        # acc/l = sum_k softmax_k(logits) * w_k * z_k; empty rows stay 0.
-        out_ref[...] = (acc / jnp.maximum(l, 1e-16)).astype(out_ref.dtype)
+            # Online softmax: rescale the accumulator by exp(m - m_new).
+            # While a row has seen no valid neighbor m is -inf and every
+            # term is 0.
+            m_new = jnp.maximum(m, logit)
+            m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+            p = jnp.where(valid, jnp.exp(logit - m_safe), 0.0)  # (BR, 1)
+            corr = jnp.exp(m - m_safe)  # exp(-inf) = 0: empty prefix
+            num = p if not has_weight else p * w_col
+            zh = zgather[slot, :, :, pl.ds(h * feat, feat)].reshape(
+                block_rows, feat).astype(jnp.float32)           # (BR, F)
+            heads_out.append((m_new, l * corr + p, acc * corr + num * zh))
+        return tuple(zip(*heads_out))
+
+    init = ((m0,) * heads, (l0,) * heads, (acc0,) * heads)
+    ms, ls, accs = jax.lax.fori_loop(0, k, body_k, init)
+    for h in range(heads):
+        lanes = pl.ds(h * feat, feat)
+        if return_carry:
+            # Unnormalised carry out: the ops layer merges (m, l, acc)
+            # triples across relation launches before the single finalize
+            # divide.
+            out_ref[:, lanes] = accs[h].astype(out_ref.dtype)
+            m_ref[h] = ms[h].astype(m_ref.dtype)
+            l_ref[h] = ls[h].astype(l_ref.dtype)
+        else:
+            # acc/l = sum_k softmax_k(logits) * w_k * z_k; empty rows stay 0.
+            out_ref[:, lanes] = (accs[h] / jnp.maximum(ls[h], 1e-16)).astype(
+                out_ref.dtype)
 
 
 def _gat_ell_kernel(*args, **kwargs):
@@ -212,9 +222,8 @@ def _gat_ell_kernel(*args, **kwargs):
 
 def _head_table(z2d: jnp.ndarray, heads: int, feat: int, padded: int):
     """(N, H*F) -> (N, 1, H*padded): each head's lanes zero-padded to whole
-    lane tiles, in the (N, 1, ·) layout the row-gather DMAs read. The out
-    block is one head's ``(BR, BF)`` slice of the ``(R, H*padded)`` output,
-    and a TPU block's last dimension must be a multiple of 128."""
+    lane tiles, in the (N, 1, ·) layout the row-gather DMAs read. Each
+    head's slice of a gathered row then starts on a lane-tile boundary."""
     n = z2d.shape[0]
     z3 = z2d.reshape(n, heads, feat)
     if padded != feat:
@@ -228,6 +237,46 @@ def _unpad_heads(out: jnp.ndarray, heads: int, feat: int, padded: int):
         return out
     return out.reshape(out.shape[0], heads, padded)[:, :, :feat].reshape(
         out.shape[0], heads * feat)
+
+
+def _grid_spec(rows: int, k: int, heads: int, row_lanes: int,
+               adst_lanes: int, *, block_rows: int, has_weight: bool,
+               carry: bool, z_dtype, a_dtype) -> pltpu.PrefetchScalarGridSpec:
+    """One grid cell per row block; every block covers all heads.
+
+    ``row_lanes`` is the padded ``H*F_pad`` width of a feature row (and of
+    the out block), ``adst_lanes`` the padded ``H*LD`` width of a
+    sender/receiver-term row. A carry launch adds the ``(1, H)`` prior
+    operand and the ``(m, l)`` outputs, laid out head-major ``(H, R, 1)``
+    as the ops layer merges them.
+    """
+    row = lambda i, idx: (i, 0)
+    in_specs = [
+        pl.BlockSpec((block_rows, k), row),
+        pl.BlockSpec((block_rows, adst_lanes), row),
+        pl.BlockSpec((block_rows, k),
+                     row if has_weight else lambda i, idx: (0, 0)),
+    ]
+    if carry:
+        in_specs.append(pl.BlockSpec((1, heads), lambda i, idx: (0, 0)))
+    # the sender term and z stay in HBM; the kernel DMA-gathers rows out.
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY),
+                 pl.BlockSpec(memory_space=pl.ANY)]
+    out_specs = pl.BlockSpec((block_rows, row_lanes), row)
+    if carry:
+        stat = pl.BlockSpec((heads, block_rows, 1), lambda i, idx: (0, i, 0))
+        out_specs = [out_specs, stat, stat]
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,  # the neighbor table: DMA address stream
+        grid=(rows // block_rows,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=[
+            pltpu.VMEM((_NUM_SLOTS, block_rows, 1, row_lanes), z_dtype),
+            pltpu.VMEM((_NUM_SLOTS, block_rows, 1, adst_lanes), a_dtype),
+            pltpu.SemaphoreType.DMA((2, _NUM_SLOTS, block_rows)),
+        ],
+    )
 
 
 @functools.partial(
@@ -257,46 +306,23 @@ def _gat_ell_pallas_impl(ell_idx: jnp.ndarray, adst: jnp.ndarray,
     feat = hf // heads
     fpad = lane_tiles(feat)
     hpad = lane_tiles(heads)
-    block_feat = DEFAULT_BF
     assert rows % block_rows == 0, (rows, block_rows)
     assert k >= 1, "ELL table must have at least one neighbor column"
-    nfb = fpad // block_feat
-    grid = (rows // block_rows, heads, nfb)
 
     has_weight = ell_w is not None
     if ell_w is None:  # dummy operand keeps the signature static
         ell_w = jnp.zeros((block_rows, k), jnp.float32)
 
     kernel = functools.partial(
-        _gat_ell_kernel, block_rows=block_rows, block_feat=block_feat, k=k,
-        heads=heads, feat=fpad, negative_slope=float(negative_slope),
-        has_weight=has_weight)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,  # the neighbor table: DMA address stream
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, k), lambda i, h, j, idx: (i, 0)),
-            pl.BlockSpec((block_rows, hpad), lambda i, h, j, idx: (i, 0)),
-            pl.BlockSpec((block_rows, k), lambda i, h, j, idx: (i, 0))
-            if has_weight else
-            pl.BlockSpec((block_rows, k), lambda i, h, j, idx: (0, 0)),
-            # alpha_src and z stay in HBM; the kernel DMA-gathers rows out.
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((block_rows, block_feat),
-                               lambda i, h, j, idx: (i, h * nfb + j)),
-        scratch_shapes=[
-            pltpu.VMEM((_NUM_SLOTS, block_rows, 1, block_feat), z2d.dtype),
-            pltpu.VMEM((_NUM_SLOTS, block_rows, 1, hpad), alpha_src.dtype),
-            pltpu.SemaphoreType.DMA((2, _NUM_SLOTS, block_rows)),
-        ],
-    )
+        _gat_ell_kernel, block_rows=block_rows, k=k, heads=heads, feat=fpad,
+        negative_slope=float(negative_slope), has_weight=has_weight)
 
     out = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
+        grid_spec=_grid_spec(rows, k, heads, heads * fpad, hpad,
+                             block_rows=block_rows, has_weight=has_weight,
+                             carry=False, z_dtype=z2d.dtype,
+                             a_dtype=alpha_src.dtype),
         out_shape=jax.ShapeDtypeStruct((rows, heads * fpad), z2d.dtype),
         interpret=interpret,
         name="_gat_ell_kernel",
@@ -373,55 +399,24 @@ def _attn_ell_pallas_impl(ell_idx: jnp.ndarray, adst: jnp.ndarray,
     feat = hf // heads
     fpad = lane_tiles(feat)
     hlpad = lane_tiles(hl)
-    block_feat = DEFAULT_BF
     assert rows % block_rows == 0, (rows, block_rows)
     assert k >= 1, "ELL table must have at least one neighbor column"
-    nfb = fpad // block_feat
-    grid = (rows // block_rows, heads, nfb)
 
     has_weight = ell_w is not None
     if ell_w is None:  # dummy operand keeps the signature static
         ell_w = jnp.zeros((block_rows, k), jnp.float32)
 
     kernel = functools.partial(
-        _attn_ell_kernel, block_rows=block_rows, block_feat=block_feat, k=k,
-        heads=heads, feat=fpad, negative_slope=float(negative_slope),
-        has_weight=has_weight, logit_kind=logit_kind, logit_dim=logit_dim,
-        return_carry=True)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, k), lambda i, h, j, idx: (i, 0)),
-            pl.BlockSpec((block_rows, hlpad), lambda i, h, j, idx: (i, 0)),
-            pl.BlockSpec((block_rows, k), lambda i, h, j, idx: (i, 0))
-            if has_weight else
-            pl.BlockSpec((block_rows, k), lambda i, h, j, idx: (0, 0)),
-            pl.BlockSpec((1, heads), lambda i, h, j, idx: (0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_rows, block_feat),
-                         lambda i, h, j, idx: (i, h * nfb + j)),
-            # (m, l) are laid out head-major, (H, R, 1): a (BR, 1) block of
-            # an (R, H) array would break the last-dimension tiling rule.
-            pl.BlockSpec((None, block_rows, 1),
-                         lambda i, h, j, idx: (h, i, 0)),
-            pl.BlockSpec((None, block_rows, 1),
-                         lambda i, h, j, idx: (h, i, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((_NUM_SLOTS, block_rows, 1, block_feat), z2d.dtype),
-            pltpu.VMEM((_NUM_SLOTS, block_rows, 1, hlpad), alpha_src.dtype),
-            pltpu.SemaphoreType.DMA((2, _NUM_SLOTS, block_rows)),
-        ],
-    )
+        _attn_ell_kernel, block_rows=block_rows, k=k, heads=heads, feat=fpad,
+        negative_slope=float(negative_slope), has_weight=has_weight,
+        logit_kind=logit_kind, logit_dim=logit_dim, return_carry=True)
 
     acc, m, l = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
+        grid_spec=_grid_spec(rows, k, heads, heads * fpad, hlpad,
+                             block_rows=block_rows, has_weight=has_weight,
+                             carry=True, z_dtype=z2d.dtype,
+                             a_dtype=alpha_src.dtype),
         out_shape=[
             jax.ShapeDtypeStruct((rows, heads * fpad), jnp.float32),
             jax.ShapeDtypeStruct((heads, rows, 1), jnp.float32),

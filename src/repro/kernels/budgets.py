@@ -98,25 +98,26 @@ def ell_launch_usage(rows: int, k: int, feat: int, *,
 def attn_launch_usage(rows: int, k: int, heads: int, feat: int, *,
                       logit_dim: int = 1,
                       block_rows: int = DEFAULT_BR,
-                      block_feat: int = DEFAULT_BF,
                       dtype_bytes: int = 4,
                       weighted: bool = False,
                       carry: bool = False) -> Dict[str, int]:
     """Static SMEM/VMEM bytes of one (chunked) typed-attention launch.
 
+    One grid cell per row block covers every head, so the feature gather
+    and the out block are whole ``heads * lane_tiles(feat)`` rows.
     ``logit_dim`` is the per-head width of the attention operands: 1 for
     additive GAT logits, the head dim D for HGT's dot-product K/Q.  A typed
     launch additionally stages a ``(1, heads)`` prior row in VMEM, and a
     carry launch (``return_carry=True``) keeps the running ``(m, l)``
-    softmax statistics as extra per-head output blocks.
+    softmax statistics as two ``(heads, BR, 1)`` output blocks.
     """
     launch_rows = min(rows, ell_chunk_rows(k, block_rows))
-    bf = min(block_feat, lane_tiles(feat))
+    row = heads * lane_tiles(feat)
     al = lane_tiles(heads * logit_dim)
     smem = launch_rows * k * _I32
-    vmem = (DOUBLE_BUFFER_SLOTS * block_rows * bf * dtype_bytes   # z gather
+    vmem = (DOUBLE_BUFFER_SLOTS * block_rows * row * dtype_bytes  # z gather
             + DOUBLE_BUFFER_SLOTS * block_rows * al * dtype_bytes  # alpha
-            + block_rows * bf * dtype_bytes                       # out block
+            + block_rows * row * dtype_bytes                      # out block
             + block_rows * al * dtype_bytes)                      # adst block
     if weighted:
         vmem += block_rows * k * dtype_bytes
@@ -124,21 +125,19 @@ def attn_launch_usage(rows: int, k: int, heads: int, feat: int, *,
     if typed:
         vmem += heads * dtype_bytes                    # (1, H) prior row
     if carry:
-        vmem += 2 * block_rows * dtype_bytes           # (BR, 1) m + l blocks
+        vmem += 2 * heads * block_rows * dtype_bytes   # (H, BR, 1) m + l
     return {"smem_bytes": smem, "vmem_bytes": vmem,
-            "launch_rows": launch_rows, "block_feat": bf}
+            "launch_rows": launch_rows, "block_feat": row}
 
 
 def gat_launch_usage(rows: int, k: int, heads: int, feat: int, *,
                      block_rows: int = DEFAULT_BR,
-                     block_feat: int = DEFAULT_BF,
                      dtype_bytes: int = 4,
                      weighted: bool = False) -> Dict[str, int]:
     """Static SMEM/VMEM bytes of one (chunked) flash-GAT launch."""
     return attn_launch_usage(rows, k, heads, feat, logit_dim=1,
-                             block_rows=block_rows, block_feat=block_feat,
-                             dtype_bytes=dtype_bytes, weighted=weighted,
-                             carry=False)
+                             block_rows=block_rows, dtype_bytes=dtype_bytes,
+                             weighted=weighted, carry=False)
 
 
 def gmm_launch_usage(k_dim: int, *, block: Tuple[int, int, int] = GMM_BLOCK,
@@ -216,8 +215,9 @@ def check_attn_bucket(rows: int, k: int, heads: int, feat: int, *,
             f"{context} (rows={rows}, K={k}, heads={heads}, feat={feat}, "
             f"logit_dim={logit_dim}, carry={carry}): "
             f"{usage['vmem_bytes']} VMEM bytes per launch exceeds the "
-            f"per-core budget of {VMEM_BYTES_PER_CORE}. Shrink the feature "
-            f"block, head count, or per-head logit width per launch.")
+            f"per-core budget of {VMEM_BYTES_PER_CORE}. Shrink the head "
+            f"count, the per-head feature width or the per-head logit "
+            f"width.")
 
 
 def check_gat_bucket(rows: int, k: int, heads: int, feat: int, *,

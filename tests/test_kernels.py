@@ -201,7 +201,12 @@ def test_raw_gmm_kernel_grad_raises_actionable(rng):
 
 # ----------------------------------------------------------- fused attention
 @pytest.mark.parametrize("shape", [(8, 3, 16, 2, 8), (16, 7, 50, 1, 128),
-                                   (24, 5, 30, 4, 16)])
+                                   (24, 5, 30, 4, 16),
+                                   # the GAT cell's 4 heads x 128 at K 16
+                                   (16, 16, 60, 4, 128),
+                                   # a head wider than one lane tile
+                                   (16, 5, 30, 2, 200),
+                                   (16, 6, 40, 8, 16)])
 def test_gat_ell_kernel_sweep(rng, shape):
     """Fused flash-GAT kernel == panel oracle across (R, K, N, H, F)."""
     from repro.kernels.attention import ref as gat_ref
@@ -220,6 +225,40 @@ def test_gat_ell_kernel_sweep(rng, shape):
         np.testing.assert_allclose(np.asarray(a).reshape(rows, h * f),
                                    np.asarray(b), rtol=1e-5, atol=1e-5)
         assert np.abs(np.asarray(b)[3]).max() == 0.0
+
+
+@pytest.mark.parametrize("weighted", [False, True])
+@pytest.mark.parametrize("logit_kind,heads,logit_dim,feat",
+                         [("dot", 8, 32, 32), ("add", 4, 1, 16)])
+def test_attn_ell_carry_kernel_sweep(rng, logit_kind, heads, logit_dim, feat,
+                                     weighted):
+    """Raw carry launch == panel oracle: the unnormalised accumulator and the
+    per-(row, head) softmax carry ``(m, l)``."""
+    from repro.kernels.attention import ref as gat_ref
+    from repro.kernels.attention.gat_attention import attn_ell_pallas
+    rows, k, n = 16, 6, 40
+    ell = rng.integers(-1, n, (rows, k)).astype(np.int32)
+    ell[3] = -1  # an all-padding row keeps m = -inf, l = 0, acc = 0
+    adst = rng.standard_normal((rows, heads, logit_dim)).astype(np.float32)
+    asrc = rng.standard_normal((n, heads, logit_dim)).astype(np.float32)
+    z = rng.standard_normal((n, heads, feat)).astype(np.float32)
+    prior = (rng.random(heads) + 0.5).astype(np.float32)
+    w = jnp.asarray(rng.random((rows, k)).astype(np.float32)) if weighted \
+        else None
+    m_ref, l_ref, acc_ref = gat_ref.attn_carry_panels(
+        jnp.asarray(ell), jnp.asarray(adst), w, jnp.asarray(asrc),
+        jnp.asarray(z), logit_kind=logit_kind, prior=jnp.asarray(prior))
+    acc, m, l = attn_ell_pallas(
+        jnp.asarray(ell), jnp.asarray(adst.reshape(rows, -1)), w,
+        jnp.asarray(prior[None]), jnp.asarray(asrc.reshape(n, -1)),
+        jnp.asarray(z.reshape(n, -1)), logit_kind=logit_kind, interpret=True)
+    for got, want in ((acc, np.asarray(acc_ref).reshape(rows, -1)),
+                      (m, m_ref), (l, l_ref)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+    assert np.all(np.asarray(m)[3] == -np.inf)
+    assert np.all(np.asarray(l)[3] == 0.0)
+    assert np.abs(np.asarray(acc)[3]).max() == 0.0
 
 
 def test_gat_ell_grad_matches_oracle(rng):

@@ -75,13 +75,20 @@ def test_spmm_ell_compiles(chip, feat, reduce, weighted):
              ((NODES, feat), jnp.float32), kernel="_spmm_ell_kernel")
 
 
-@pytest.mark.parametrize("heads,feat", [(4, 64), (1, 256)])
-def test_gat_ell_compiles(chip, heads, feat):
-    """Additive (GAT) attention: F=64 is narrower than one lane tile."""
+@pytest.mark.parametrize("heads,feat,rows", [
+    pytest.param(4, 64, ROWS, id="4-64"),
+    pytest.param(1, 256, ROWS, id="1-256"),
+    pytest.param(4, 128, ROWS, id="4-128"),
+    pytest.param(4, 128, ell_chunk_rows(K), id="4-128-chunk"),
+])
+def test_gat_ell_compiles(chip, heads, feat, rows):
+    """Additive (GAT) attention: F=64 is narrower than one lane tile; 4 x 128
+    is the GAT cell's width, also at the largest SMEM chunk a K=16 layer-0
+    bucket launches over (``ell_chunk_rows(16)`` = 4096 rows)."""
     def fn(idx, adst, asrc, z):
         return gat_ell_pallas(idx, adst, None, asrc, z)
 
-    _compile(chip, fn, ((ROWS, K), jnp.int32), ((ROWS, heads), jnp.float32),
+    _compile(chip, fn, ((rows, K), jnp.int32), ((rows, heads), jnp.float32),
              ((NODES, heads), jnp.float32),
              ((NODES, heads * feat), jnp.float32), kernel="_gat_ell_kernel")
 
